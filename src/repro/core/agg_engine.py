@@ -50,10 +50,12 @@ the streaming reference (left-fold accumulate, single divide, f32 cast), so
 ``avg_flat`` is **bit-identical** — the paper's invariance-by-construction
 property, enforced in ``tests/test_agg_engine.py``.
 
-Caveat: the Pallas path shares the accumulation order but may differ by
-≤1 ulp in the final division (XLA reciprocal strength-reduction), and in
-interpret mode (non-TPU hosts) it is far slower than the numpy evaluator —
-hence it is only auto-enabled on TPU backends.
+The Pallas path shares the accumulation order and returns sums; the
+single divide runs on the host with the evaluator's f32 op (one divide
+rule on every backend), so it is bit-identical too. In interpret mode
+(non-TPU hosts) it is far slower than the numpy evaluator — hence
+:func:`repro.kernels.ops.kernel_mode` turns it on by itself only on TPU
+backends.
 
 Selection: pass ``engine="streaming" | "batched" | "incremental" |
 "host_mesh"`` to ``aggregate_round`` (or any topology function), or set
@@ -424,6 +426,9 @@ class ExecutionBackend:
     def finalize(self, acc, weights, n):
         raise NotImplementedError
 
+    #: fold nodes the last ``end_round`` evaluated with the Pallas kernel
+    kernel_folds = 0
+
     def nbytes(self, x) -> int:
         return int(x.nbytes)
 
@@ -564,7 +569,8 @@ class IncrementalBackend(ExecutionBackend):
 class BatchedBackend(ExecutionBackend):
     """Deferred backend: bodies build a DAG of :class:`LazyAverage` nodes;
     ``end_round`` evaluates it vectorized (numpy chunked fold, or the Pallas
-    ``fedavg_multi`` kernel for unweighted nodes on TPU hosts)."""
+    ``fedavg_multi`` kernel for unweighted nodes on TPU hosts; the count
+    lands in ``kernel_folds``)."""
 
     name = "batched"
 
@@ -610,19 +616,14 @@ class BatchedBackend(ExecutionBackend):
     def _pallas_enabled(self) -> bool:
         if self._use_pallas is not None:
             return self._use_pallas
-        env = knobs.env_pallas()
-        if env is not None:
-            return env
-        try:
-            import jax
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        from repro.kernels import ops as kops
+        return kops.kernel_mode() is not None
 
-    def _evaluate_pallas(self) -> None:
+    def _evaluate_pallas(self) -> int:
         """Dispatch unweighted pending nodes whose inputs are all concrete
-        (no lazy ancestors) to the fused Pallas kernel — one launch per
-        client count. May differ from numpy by ≤1 ulp in the division."""
+        (no lazy ancestors) to the Pallas fold — one byte-bounded
+        ``fedavg_multi`` per client count, reading inputs in place (no
+        whole-round stack). Returns the number of nodes it folded."""
         from repro.kernels import ops as kops
 
         ready = [nd for nd in self._nodes
@@ -635,15 +636,16 @@ class BatchedBackend(ExecutionBackend):
         # detlint: allow[ORD001] by_n is insertion-ordered by ready-node
         # creation order; each bucket evaluates independently
         for nds in by_n.values():
-            stacks = [np.stack([np.asarray(_materialize(x), np.float32)
-                                for x in nd.inputs]) for nd in nds]
-            outs = kops.fedavg_multi(stacks, workers=self._pool.workers)
+            outs = kops.fedavg_multi([nd.inputs for nd in nds],
+                                     workers=self._pool.workers,
+                                     read=_chunk_of)
             for nd, out in zip(nds, outs):
-                nd.out = np.asarray(out, np.float32)
+                nd.out = out
+        return len(ready)
 
     def end_round(self, store: ObjectStore) -> None:
-        if self._pallas_enabled():
-            self._evaluate_pallas()
+        self.kernel_folds = self._evaluate_pallas() \
+            if self._pallas_enabled() else 0
         _evaluate_nodes(self._nodes, pool=self._pool)
         for key in store.list():
             v = store.peek(key)
@@ -661,7 +663,7 @@ class HostMeshBackend(BatchedBackend):
 
     Same deferred-DAG recording as :class:`BatchedBackend`; at round end,
     unweighted nodes whose inputs are all concrete dispatch through
-    :func:`repro.core.device_agg.mesh_fold_sum` — a ``compat.shard_map``
+    :func:`repro.core.device_agg.mesh_fold_sum` — a ``jax.shard_map``
     left-fold over a 1-D mesh of host CPU devices
     (``XLA_FLAGS=--xla_force_host_platform_device_count=N``), each device
     owning a contiguous element shard — then divide on the host with the

@@ -14,7 +14,8 @@ The serverless architectures map onto mesh collectives (DESIGN.md §3):
 
 All functions run inside ``shard_map`` with per-device views; M = product of
 the replica axis sizes. Used by the ZeRO trainer (`launch/train.py`) and
-verified against the serverless path on 8 fake CPU devices.
+verified against the host numpy mean on 8 fake CPU devices and on a
+four-chip v5e host (``chip_smoke.py --chips 4``).
 """
 from __future__ import annotations
 
@@ -26,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 Pytree = Any
 
@@ -37,17 +37,6 @@ Pytree = Any
 
 def pmean(tree: Pytree, axes) -> Pytree:
     return jax.tree.map(lambda g: lax.pmean(g, axes), tree)
-
-
-def psum_scatter_mean(flat: jax.Array, axis: str) -> jax.Array:
-    """Per-device flat gradient -> this device's averaged shard.
-
-    flat must be divisible by the axis size; callers pad via
-    ``pad_to_multiple``.
-    """
-    size = lax.psum(1, axis)
-    return lax.psum_scatter(flat, axis, scatter_dimension=0,
-                            tiled=True) / size
 
 
 def all_gather_flat(shard: jax.Array, axis: str) -> jax.Array:
@@ -84,13 +73,17 @@ def _replica_axes(mesh: Mesh) -> tuple[str, ...]:
 
 def all_reduce_mean(mesh: Mesh, grads: Pytree,
                     hierarchical: bool = False) -> Pytree:
-    """Full-gradient aggregation over the replica axes (λ-FL analogue)."""
-    axes = _replica_axes(mesh)
-    spec = P()  # replicated within replica axes (per-device full grad)
+    """Full-gradient aggregation over the replica axes (λ-FL analogue).
 
-    @partial(shard_map, mesh=mesh, in_specs=spec, out_specs=spec,
+    Every leaf of ``grads`` stacks one contribution per replica on its
+    leading axis (length = replica count, sharded over the replica axes);
+    every device ends with the full mean, replicated."""
+    axes = _replica_axes(mesh)
+
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axes), out_specs=P(),
              check_vma=False)
     def agg(g):
+        g = jax.tree.map(lambda x: x[0], g)
         if hierarchical and len(axes) > 1:
             return hierarchical_mean(g, axes[-1], axes[0])
         return pmean(g, axes)
@@ -98,25 +91,26 @@ def all_reduce_mean(mesh: Mesh, grads: Pytree,
     return agg(grads)
 
 
-def reduce_scatter_mean_flat(mesh: Mesh, flat: jax.Array) -> jax.Array:
-    """GradsSharding: flat (padded) gradient -> per-device averaged shard.
+def reduce_scatter_mean_flat(mesh: Mesh, stack: jax.Array) -> jax.Array:
+    """GradsSharding: per-replica flat gradients -> per-device mean shard.
 
-    Input is replicated over replica axes; output is sharded over them
-    (device d owns shard d)."""
+    ``stack`` is (M, L), one contribution per replica (row r on replica
+    r, sharded over the replica axes), L a multiple of M (callers pad via
+    ``pad_to_multiple``). Output is (L,) sharded over the same axes:
+    device d owns averaged shard d."""
     axes = _replica_axes(mesh)
 
-    @partial(shard_map, mesh=mesh, in_specs=P(), out_specs=P(axes),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axes), out_specs=P(axes),
              check_vma=False)
     def agg(g):
-        out = g
-        for ax in axes:
-            out = psum_scatter_mean(out, ax) * lax.psum(1, ax)
+        out = g[0]
         m = 1
         for ax in axes:
+            out = lax.psum_scatter(out, ax, scatter_dimension=0, tiled=True)
             m *= lax.psum(1, ax)
         return out / m
 
-    return agg(flat)
+    return agg(stack)
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +163,7 @@ def mesh_fold_sum(mesh: Mesh, stack) -> "jax.Array":
     m = mesh.devices.size
     padded, _pad = pad_to_multiple_cols(stack, m)
 
-    @partial(shard_map, mesh=mesh, in_specs=P(None, "fold"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(None, "fold"),
              out_specs=P("fold"), check_vma=False)
     def fold(block):
         out = block[0]
@@ -192,7 +186,7 @@ def all_gather_shards(mesh: Mesh, shards: jax.Array) -> jax.Array:
     """Step 4: reconstruct the full flat vector from per-device shards."""
     axes = _replica_axes(mesh)
 
-    @partial(shard_map, mesh=mesh, in_specs=P(axes), out_specs=P(),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axes), out_specs=P(),
              check_vma=False)
     def gather(s):
         out = s

@@ -265,6 +265,9 @@ class AggregationResult:
     memory_mb: float = 0.0
     peak_memory_mb: float = 0.0
     engine: str = "streaming"
+    # fold nodes the engine evaluated with the Pallas kernel (compiled on
+    # a TPU, interpreted where forced); the rest ran on the host
+    kernel_folds: int = 0
     schedule: str = "barrier"
     readahead_k: int = 1
     # the wire codec contributions travelled under, and — for lossy
@@ -1147,8 +1150,8 @@ def run_round(topology: str | Topology,
         puts=store.stats.puts - p0, gets=store.stats.gets - g0,
         memory_mb=max(r.memory_mb for r in recs),
         peak_memory_mb=max(r.peak_memory_mb for r in recs),
-        engine=backend.name, schedule=sched, readahead_k=readahead,
-        codec=cdc.name,
+        engine=backend.name, kernel_folds=backend.kernel_folds,
+        schedule=sched, readahead_k=readahead, codec=cdc.name,
         codec_error=_codec_error(cdc, avg, sub, fold_weights)
         if track_codec_error else float("nan"),
         round_start_s=base, round_end_s=round_end,

@@ -10,9 +10,12 @@ accumulation pattern). Memory per core = one accumulator block + one
 incoming block — exactly the paper's two-buffer O(|θ|/M) bound, shrunk from
 Lambda-RAM scale to VMEM-tile scale.
 
-Accumulation order is client-by-client per element, matching the serverless
-streaming implementation's order exactly (the final division may differ by
-≤1 ulp where XLA strength-reduces divide to reciprocal-multiply).
+The kernel returns the running **sum**; the single divide is the caller's
+(``kernels/ops.py`` divides on the host with the numpy evaluator's f32 op),
+so there is one divide rule on every backend. Accumulation order is
+client-by-client per element, the serverless streaming implementation's
+order exactly, so an unweighted sum is bit-identical to its f32 left-fold.
+Per-client weights live in SMEM and are read as scalars per grid step.
 """
 from __future__ import annotations
 
@@ -21,15 +24,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 32
 
 
-def _fedavg_kernel(x_ref, w_ref, o_ref, *, n_clients: int):
+def _fold_kernel(*refs, weighted: bool):
     """Grid: (row_blocks, N); client index iterates fastest."""
+    if weighted:
+        x_ref, w_ref, o_ref = refs
+    else:
+        x_ref, o_ref = refs
     n = pl.program_id(1)
-    contrib = x_ref[0].astype(jnp.float32) * w_ref[0]
+    contrib = x_ref[0].astype(jnp.float32)
+    if weighted:
+        contrib = contrib * w_ref[n]
 
     @pl.when(n == 0)
     def _init():
@@ -40,48 +50,27 @@ def _fedavg_kernel(x_ref, w_ref, o_ref, *, n_clients: int):
         o_ref[...] += contrib
 
 
-def _finalize_kernel(acc_ref, tw_ref, o_ref):
-    o_ref[...] = acc_ref[...] / tw_ref[0]
-
-
 def fedavg_stream(stacked: jax.Array, weights: jax.Array | None = None, *,
                   block_rows: int = DEFAULT_BLOCK_ROWS,
                   interpret: bool = False) -> jax.Array:
-    """stacked: (N, R, 128) client shards -> (R, 128) f32 weighted mean.
+    """stacked: (N, R, 128) client shards -> (R, 128) f32 sum Σ w_i x_i.
 
     R must be a multiple of ``block_rows`` (ops.py pads). ``weights`` is
-    (N,) f32; None = uniform (divide by N).
+    (N,) f32 held whole in SMEM; None = unweighted (no multiply at all).
     """
     n, r, lanes = stacked.shape
     assert lanes == LANES, f"last dim must be {LANES}, got {lanes}"
     assert r % block_rows == 0, (r, block_rows)
-    if weights is None:
-        weights = jnp.ones((n,), jnp.float32)
-    total = jnp.sum(weights)
-
-    grid = (r // block_rows, n)
-    acc = pl.pallas_call(
-        functools.partial(_fedavg_kernel, n_clients=n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_rows, LANES), lambda i, j: (j, i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (j,)),
-        ],
+    in_specs = [pl.BlockSpec((1, block_rows, LANES), lambda i, j: (j, i, 0))]
+    args = [stacked]
+    if weights is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(jnp.asarray(weights, jnp.float32))
+    return pl.pallas_call(
+        functools.partial(_fold_kernel, weighted=weights is not None),
+        grid=(r // block_rows, n),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.float32),
         interpret=interpret,
-    )(stacked, weights)
-
-    # Separate tiny finalize pass keeps the accumulate kernel write-only on
-    # its output blocks (no read-modify-write of the division).
-    return pl.pallas_call(
-        _finalize_kernel,
-        grid=(r // block_rows,),
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.float32),
-        interpret=interpret,
-    )(acc, total[None])
+    )(*args)

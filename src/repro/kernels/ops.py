@@ -1,16 +1,21 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Handle flat-vector ⇄ (rows, 128) tiling, padding to block multiples, and
-interpret-mode selection (interpret=True on CPU hosts — the kernel bodies
-execute in Python for validation; on TPU they lower to Mosaic).
+interpret-mode selection (interpret=True off TPU — the kernel bodies
+execute in Python for validation; on TPU they lower to Mosaic and never
+run interpreted). :func:`kernel_mode` is the one switch between these
+kernels and the numpy mirrors of the aggregation engine and wire codecs.
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro import knobs
 from repro.kernels import fedavg_stream as _fa
 from repro.kernels import fused_sgd as _sgd
 from repro.kernels import quantize as _q
@@ -22,6 +27,22 @@ LANES = 128
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def kernel_mode() -> str | None:
+    """Where the aggregation path's kernels run, or None for numpy.
+
+    ``"compiled"`` on a TPU backend — never interpret mode there;
+    ``"interpret"`` on any other backend when ``REPRO_AGG_PALLAS`` forces
+    the kernels (CPU tests); None — the engine's and codecs' numpy
+    mirrors — otherwise, and wherever ``REPRO_AGG_PALLAS=0``.
+    """
+    env = knobs.env_pallas()
+    if env is False:
+        return None
+    if not _use_interpret():
+        return "compiled"
+    return "interpret" if env else None
 
 
 def _to_tiles(flat: jax.Array, block_rows: int) -> tuple[jax.Array, int]:
@@ -40,81 +61,141 @@ def _from_tiles(tiles: jax.Array, l: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# fold: byte-bounded launches of the fedavg_stream kernel
+# ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _fedavg_flat(stacked_flat, weights, block_rows, interpret):
-    tiles, l = _to_tiles(stacked_flat, block_rows)
-    out = _fa.fedavg_stream(tiles, weights, block_rows=block_rows,
-                            interpret=interpret)
-    return _from_tiles(out, l)
+def _fold_sum(tiles, weights, block_rows, interpret):
+    return _fa.fedavg_stream(tiles, weights, block_rows=block_rows,
+                             interpret=interpret)
 
 
-def fedavg_shards(client_shards: jax.Array,
-                  weights: jax.Array | None = None,
-                  block_rows: int = 32,
-                  interpret: bool | None = None) -> jax.Array:
-    """client_shards: (N, L) flat shards -> (L,) f32 weighted mean."""
-    if interpret is None:
-        interpret = _use_interpret()
-    return _fedavg_flat(client_shards, weights, block_rows, interpret)
+def fold_budget_bytes() -> int | None:
+    """Bytes one fold launch may hold on the default device: half of what
+    its allocator has free (``memory_stats()``), so a launch's input and
+    output fit beside whatever the previous launch is still releasing.
+    None where the backend reports no limit (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return (int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))) \
+        // 2
 
 
-def _fedavg_fused(stacks, weights, block_rows, interpret) -> list:
-    """Fuse a bucket of (N, L_j) stacks into one launch; split back."""
-    lengths = [int(s.shape[1]) for s in stacks]
-    fused = stacks[0] if len(stacks) == 1 \
-        else jnp.concatenate(stacks, axis=1)
-    avg = fedavg_shards(fused, weights, block_rows=block_rows,
-                        interpret=interpret)
-    out, off = [], 0
-    for l in lengths:
-        out.append(avg[off:off + l])
-        off += l
-    return out
+def fold_windows(total: int, n: int, budget_bytes: int | None,
+                 parts: int = 1, block_rows: int = 32) -> list:
+    """Cut ``total`` fold columns of an N-client round into launch windows.
+
+    Each window is ``[start, stop)`` with a tile-aligned start; its
+    launch holds an (N, cols) f32 input and a (cols,) f32 output, cols
+    padded to the kernel tile, within ``budget_bytes`` (None = no bound).
+    Windows are as equal as the tiles allow and at least ``parts`` in
+    number when there are enough tiles (the interpret-mode fold pool).
+    """
+    tile = block_rows * LANES
+    n_tiles = -(-total // tile)
+    if n_tiles == 0:
+        return []
+    cap = n_tiles
+    if budget_bytes is not None:
+        cap = budget_bytes // (4 * (n + 1) * tile)
+        if cap < 1:
+            raise ValueError(
+                f"one {n}-client fold tile needs {4 * (n + 1) * tile} B, "
+                f"more than the device budget of {budget_bytes} B")
+    n_win = max(-(-n_tiles // cap), min(parts, n_tiles))
+    per = -(-n_tiles // n_win) * tile
+    return [(s, min(s + per, total)) for s in range(0, total, per)]
 
 
-def fedavg_multi(shard_stacks, weights: jax.Array | None = None,
+def _slice_rows(x, s: int, e: int):
+    return x[s:e]
+
+
+def fedavg_multi(shard_stacks: Sequence, weights=None,
                  block_rows: int = 32,
                  interpret: bool | None = None,
-                 workers: int | str | None = None) -> list:
-    """Batched multi-shard entry point: average M shard stacks in ONE kernel
-    launch instead of M.
+                 workers: int | str | None = None,
+                 read: Callable | None = None) -> list:
+    """Average M shard stacks of one round in byte-bounded kernel launches.
 
-    ``shard_stacks`` is a sequence of (N, L_j) arrays — all M shards of the
-    same round, every stack holding the same N clients in the same order.
-    The stacks are concatenated along L into a single (N, ΣL_j) launch (one
-    grid, one pad) and the averaged vector is split back per shard. Because
-    FedAvg is element-wise, each slice is exactly ``fedavg_shards`` of the
-    corresponding stack.
+    ``shard_stacks`` is a sequence of stacks, each N rows of one shard —
+    an (N, L_j) array, or a sequence of N row objects that ``read(row,
+    start, stop)`` slices (default: ``row[start:stop]``) — every stack
+    holding the same N clients in the same order. The stacks' columns
+    are laid end to end and cut by :func:`fold_windows` so that each
+    launch fits :func:`fold_budget_bytes`; each window is built on the
+    host, moved to the device, folded and copied back before the next is
+    built. The kernel returns sums, and the mean is one f32 divide on the
+    host — the numpy evaluator's op — so an unweighted result is
+    bit-identical to the streaming reference, and averaging being
+    element-wise, to any other windowing.
 
-    ``workers`` > 1 splits the stack list into that many contiguous buckets
-    and fuses each bucket as its own launch on the host fold pool —
-    interpret mode only, where launches are host-bound; averaging is
-    element-wise, so the per-shard results are bit-identical to the
-    single-launch path at any worker count. On TPU the single fused launch
-    is kept regardless.
+    ``workers`` > 1 runs windows on the host fold pool — interpret mode
+    only, where launches are host-bound; on TPU windows run one at a
+    time so only one holds device memory.
 
     Returns a list of (L_j,) f32 means, one per input stack.
     """
     if interpret is None:
         interpret = _use_interpret()
-    stacks = [jnp.asarray(s) for s in shard_stacks]
+    read = read or _slice_rows
+    stacks = [np.asarray(s) if isinstance(s, jax.Array) else s
+              for s in shard_stacks]
     if not stacks:
         return []
-    n = stacks[0].shape[0]
-    assert all(s.shape[0] == n for s in stacks), \
+    n = len(stacks[0])
+    assert all(len(s) == n for s in stacks), \
         "all shard stacks must hold the same N clients"
+    lengths = [int(s[0].shape[0]) for s in stacks]
+    offsets = np.cumsum([0] + lengths).tolist()
+    if weights is None:
+        w_dev, div = None, np.float32(float(n))
+    else:
+        w_host = np.asarray(weights, np.float32)
+        w_dev, div = jnp.asarray(w_host), np.float32(w_host.sum())
+    outs = [np.empty(l, np.float32) for l in lengths]
     from repro.core.fold_pool import get_pool
     pool = get_pool(workers)
-    if not interpret or pool.workers <= 1 or len(stacks) <= 1:
-        return _fedavg_fused(stacks, weights, block_rows, interpret)
-    nb = min(pool.workers, len(stacks))
-    per = -(-len(stacks) // nb)
-    buckets = [stacks[i:i + per] for i in range(0, len(stacks), per)]
-    parts = pool.map(
-        lambda b: _fedavg_fused(b, weights, block_rows, interpret),
-        [(b,) for b in buckets])
-    return [v for part in parts for v in part]
+    parts = pool.workers if interpret else 1
+    windows = fold_windows(offsets[-1], n, fold_budget_bytes(), parts,
+                           block_rows)
+
+    def run(a: int, b: int) -> None:
+        tile = block_rows * LANES
+        cols = -(-(b - a) // tile) * tile
+        buf = np.empty((n, cols), np.float32)
+        buf[:, b - a:] = 0.0
+        segs = []
+        for j, (off, l) in enumerate(zip(offsets, lengths)):
+            lo, hi = max(a, off), min(b, off + l)
+            if lo < hi:
+                segs.append((j, lo, hi))
+                for i, row in enumerate(stacks[j]):
+                    buf[i, lo - a:hi - a] = read(row, lo - off, hi - off)
+        tiles = jax.device_put(buf.reshape(n, -1, LANES))
+        del buf
+        total = np.asarray(_fold_sum(tiles, w_dev, block_rows, interpret))
+        del tiles
+        total = total.reshape(-1)
+        for j, lo, hi in segs:
+            off = offsets[j]
+            np.divide(total[lo - a:hi - a], div,
+                      out=outs[j][lo - off:hi - off])
+
+    if interpret:
+        pool.map(run, windows)
+    else:
+        for a, b in windows:
+            run(a, b)
+    return outs
+
+
+def fedavg_shards(client_shards, weights=None, block_rows: int = 32,
+                  interpret: bool | None = None) -> np.ndarray:
+    """client_shards: (N, L) flat shards -> (L,) f32 weighted mean."""
+    return fedavg_multi([client_shards], weights, block_rows=block_rows,
+                        interpret=interpret, workers=1)[0]
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -15,14 +15,13 @@ QMAX = 127.0
 
 def fedavg_stream_ref(stacked: jax.Array,
                       weights: jax.Array | None = None) -> jax.Array:
-    """(N, R, 128) -> (R, 128): client-at-a-time weighted accumulation."""
-    n = stacked.shape[0]
+    """(N, R, 128) -> (R, 128): client-at-a-time (weighted) sum."""
     if weights is None:
-        weights = jnp.ones((n,), jnp.float32)
+        weights = jnp.ones((stacked.shape[0],), jnp.float32)
     acc = stacked[0].astype(jnp.float32) * weights[0]
-    for i in range(1, n):
+    for i in range(1, stacked.shape[0]):
         acc = acc + stacked[i].astype(jnp.float32) * weights[i]
-    return acc / jnp.sum(weights)
+    return acc
 
 
 def quantize_ref(x: jax.Array, block_rows: int = 32):

@@ -46,6 +46,10 @@ ENV_PALLAS = "REPRO_AGG_PALLAS"
 #: (``repro.launch.hostenv.maybe_preload_tcmalloc``) with ``off``/``0``
 ENV_TCMALLOC = "REPRO_TCMALLOC"
 
+#: launcher-side: JAX's own persistent compile-cache directory; when set,
+#: JAX reads it and ``repro.launch.hostenv.enable_compile_cache`` keeps it
+ENV_COMPILE_CACHE = "JAX_COMPILATION_CACHE_DIR"
+
 ALL_KNOBS = (ENV_ENGINE, ENV_SCHEDULE, ENV_READAHEAD, ENV_CODEC,
              ENV_FAULTS, ENV_WORKERS, ENV_PALLAS)
 
@@ -87,6 +91,10 @@ def env_workers(default=None):
 
 def env_tcmalloc() -> str:
     return os.environ.get(ENV_TCMALLOC, "")
+
+
+def env_compile_cache() -> str:
+    return os.environ.get(ENV_COMPILE_CACHE, "")
 
 
 def env_pallas() -> bool | None:

@@ -41,7 +41,8 @@ from repro.config import (
 )
 from repro.configs import ASSIGNED, get_arch
 from repro.launch import partitioning as parts
-from repro.launch.hostenv import host_timer, maybe_preload_tcmalloc
+from repro.launch.hostenv import (enable_compile_cache, host_timer,
+                                  maybe_preload_tcmalloc)
 from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.launch.serve import make_serve_step
 from repro.launch.train import jit_train_step
@@ -411,4 +412,5 @@ def main(argv=None):
 
 if __name__ == "__main__":
     maybe_preload_tcmalloc()
+    enable_compile_cache()
     raise SystemExit(main())

@@ -9,6 +9,10 @@ Two things live here, both deliberately *outside* the event-time planes:
   through this helper so detlint's DET002 contract stays auditable at a
   single suppression site.
 
+* :func:`enable_compile_cache` — JAX's persistent compile cache at a
+  fixed path, so a second process (or a second run on the same disk)
+  reuses the first one's compiled kernels.
+
 * :func:`maybe_preload_tcmalloc` — the SNIPPETS.md olmax idiom: re-exec
   the interpreter under ``LD_PRELOAD=libtcmalloc`` (plus the
   large-alloc-report silencer) when a tcmalloc is installed and not
@@ -26,6 +30,29 @@ import sys
 import time
 
 from repro import knobs
+
+
+#: the repository root (``src/repro/launch/hostenv.py`` -> three up)
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    nothing else is set here. Otherwise the cache goes to
+    ``<repo>/.jax_cache`` — a fixed path, because the path is part of
+    what a later process must find. Called from launcher ``__main__``
+    guards and ``chip_smoke.py``, never at import.
+    """
+    path = knobs.env_compile_cache()
+    if path:
+        return path
+    import jax
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def host_timer() -> float:

@@ -7,7 +7,8 @@ smoke tests and benches see the host's single real device.
 """
 from __future__ import annotations
 
-from repro.compat import make_auto_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +16,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     512 chips ("pod","data","model"); the pod axis is the DCI domain."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (tests use small fake-device meshes like (2,2,2))."""
-    return make_auto_mesh(shape, axes)
+    """Arbitrary mesh, every axis Auto (tests use small fake-device meshes
+    like (2,2,2))."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

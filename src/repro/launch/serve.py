@@ -19,7 +19,8 @@ from jax.sharding import Mesh
 
 from repro.config import ModelConfig, ShapeConfig
 from repro.launch import partitioning as parts
-from repro.launch.hostenv import host_timer, maybe_preload_tcmalloc
+from repro.launch.hostenv import (enable_compile_cache, host_timer,
+                                  maybe_preload_tcmalloc)
 from repro.models import registry as models
 
 Pytree = Any
@@ -107,4 +108,5 @@ def main(argv=None):
 
 if __name__ == "__main__":
     maybe_preload_tcmalloc()
+    enable_compile_cache()
     main()

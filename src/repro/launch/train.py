@@ -40,7 +40,8 @@ from repro.config import ModelConfig, ShapeConfig, ShardingPlan
 from repro.core import device_agg
 from repro.core.sharding import flatten, unflatten
 from repro.launch import partitioning as parts
-from repro.launch.hostenv import host_timer, maybe_preload_tcmalloc
+from repro.launch.hostenv import (enable_compile_cache, host_timer,
+                                  maybe_preload_tcmalloc)
 from repro.models import registry as models
 from repro.optim import Optimizer, adamw, apply_updates
 
@@ -160,9 +161,8 @@ def make_shardmap_train_step(cfg: ModelConfig, mesh: Mesh, lr: float,
             idx = idx * jax.lax.psum(1, ax) + jax.lax.axis_index(ax)
         return idx
 
-    from repro.compat import shard_map
     b_axes = rep if len(rep) > 1 else rep[0]
-    smapped = shard_map(
+    smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(), P(rep if len(rep) > 1 else rep[0]),
                   {"tokens": P(b_axes, None), "labels": P(b_axes, None)}),
@@ -351,4 +351,5 @@ def main(argv=None):
 
 if __name__ == "__main__":
     maybe_preload_tcmalloc()
+    enable_compile_cache()
     main()

@@ -66,7 +66,6 @@ def moe_block(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 def _moe_block_local(p: dict, x: jax.Array, cfg: ModelConfig, mesh):
 
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models.meshctx import replica_axes
 
@@ -92,8 +91,8 @@ def _moe_block_local(p: dict, x: jax.Array, cfg: ModelConfig, mesh):
     if cfg.gated_mlp:
         in_specs.append(P(None, None, "model"))
         args.append(p["w3"])
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=P(bspec, None, None), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=P(bspec, None, None), check_vma=False)
     return fn(*args)
 
 

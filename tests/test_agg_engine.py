@@ -238,8 +238,9 @@ def test_streaming_ops_match_seed_semantics():
 
 
 # ---------------------------------------------------------------------------
-# Pallas path (interpret mode on CPU hosts): same accumulation order,
-# division may differ by <= 1 ulp — hence allclose, not array_equal
+# Pallas path (interpret mode on CPU hosts): same accumulation order, the
+# kernel returns sums and the divide is the evaluator's f32 op on the host
+# — hence array_equal, not allclose
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
@@ -247,9 +248,9 @@ def test_batched_pallas_backend_close():
     backend = BatchedBackend(use_pallas=True)
     b = _run("gradssharding", backend, n=5, size=2_048, n_shards=2)
     a = _run("gradssharding", "streaming", n=5, size=2_048, n_shards=2)
-    np.testing.assert_allclose(b[0].avg_flat, a[0].avg_flat,
-                               rtol=2e-7, atol=1e-9)
+    assert np.array_equal(b[0].avg_flat, a[0].avg_flat)
     assert a[0].puts == b[0].puts and a[0].gets == b[0].gets
+    assert (b[0].kernel_folds, a[0].kernel_folds) == (2, 0)
 
 
 @pytest.mark.slow
@@ -261,5 +262,32 @@ def test_fedavg_multi_matches_per_shard_calls():
     multi = ops.fedavg_multi(stacks)
     for stack, got in zip(stacks, multi):
         single = ops.fedavg_shards(np.asarray(stack))
-        np.testing.assert_allclose(np.asarray(got), np.asarray(single),
-                                   rtol=1e-6, atol=1e-7)
+        assert np.array_equal(got, single)
+
+
+@pytest.mark.parametrize("tiles_per_launch", [1, 2, 5])
+def test_fedavg_multi_byte_bounded_windows(monkeypatch, tiles_per_launch):
+    """A device budget of a few kernel tiles cuts the round's columns into
+    many launches — across shard boundaries — and every cut stays
+    bit-identical to the streaming f32 left-fold + one f32 divide."""
+    from repro.kernels import ops
+    n, tile = 4, 32 * 128
+    budget = 4 * (n + 1) * tile * tiles_per_launch
+    monkeypatch.setattr(ops, "fold_budget_bytes", lambda: budget)
+    rng = np.random.default_rng(12)
+    stacks = [rng.standard_normal((n, l)).astype(np.float32)
+              for l in (5_000, 4_096, 3, 9_001)]
+    windows = ops.fold_windows(sum(s.shape[1] for s in stacks), n, budget)
+    assert len(windows) >= -(-5 // tiles_per_launch)
+    assert all(b - a <= tile * tiles_per_launch for a, b in windows)
+    for stack, got in zip(stacks, ops.fedavg_multi(stacks, workers=1)):
+        acc = stack[0].copy()
+        for row in stack[1:]:
+            acc += row
+        assert np.array_equal(got, acc / np.float32(n))
+
+
+def test_fold_windows_refuses_a_tile_over_budget():
+    from repro.kernels import ops
+    with pytest.raises(ValueError, match="device budget"):
+        ops.fold_windows(10_000, 20, 1_000)

@@ -2,8 +2,8 @@
 test process keeps its single-device view.
 
 Verifies DESIGN.md §3's central mapping: reduce-scatter gradient sharding
-(GradsSharding on TPU) is numerically identical to full-gradient all-reduce
-(λ-FL analogue) and to the serverless numpy implementation.
+(GradsSharding on TPU) over per-replica contributions agrees with
+full-gradient all-reduce (λ-FL analogue) and with the host numpy mean.
 """
 import os
 import subprocess
@@ -39,35 +39,39 @@ def test_device_count_isolated():
 
 def test_reduce_scatter_equals_allreduce_equals_numpy():
     run_subprocess("""
+        from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.core import device_agg
 
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+        rows = NamedSharding(mesh, P(("pod", "data")))
         rng = np.random.default_rng(0)
-        # a "gradient" replicated view; per-replica values differ via psum
-        # emulation: use a replicated tree and check mean collectives agree
-        tree = {"a": jnp.asarray(rng.standard_normal((4, 6)), jnp.float32),
-                "b": jnp.asarray(rng.standard_normal(17), jnp.float32)}
+        # one distinct contribution per replica (pod*data = 4), stacked on
+        # the leading axis and sharded so replica r holds row r
+        tree = {"a": rng.standard_normal((4, 4, 6)).astype(np.float32),
+                "b": rng.standard_normal((4, 17)).astype(np.float32)}
+        ref = {k: v.mean(axis=0) for k, v in tree.items()}
+        dev = {k: jax.device_put(v, rows) for k, v in tree.items()}
 
-        # all-reduce mean of replicated data is identity
-        ar = device_agg.all_reduce_mean(mesh, tree)
-        for k in tree:
-            np.testing.assert_allclose(np.asarray(ar[k]),
-                                       np.asarray(tree[k]), rtol=1e-6)
-        hr = device_agg.all_reduce_mean(mesh, tree, hierarchical=True)
-        for k in tree:
-            np.testing.assert_allclose(np.asarray(hr[k]),
-                                       np.asarray(tree[k]), rtol=1e-6)
+        for hier in (False, True):
+            ar = device_agg.all_reduce_mean(mesh, dev, hierarchical=hier)
+            for k in tree:
+                np.testing.assert_allclose(np.asarray(ar[k]), ref[k],
+                                           rtol=1e-6, atol=1e-7)
 
-        # reduce-scatter + all-gather reconstructs the mean exactly
-        from repro.core.sharding import flatten, unflatten
-        flat, spec = flatten(tree)
-        flat_p, pad = device_agg.pad_to_multiple(flat, 4)  # pod*data = 4
-        shards = device_agg.reduce_scatter_mean_flat(mesh, flat_p)
-        full = device_agg.all_gather_shards(mesh, shards)
-        if pad:
-            full = full[:-pad]
-        np.testing.assert_allclose(np.asarray(full), np.asarray(flat),
+        # reduce-scatter + all-gather reconstructs the mean
+        flat = tree["b"]
+        pad = (-flat.shape[1]) % 4
+        flat_p = np.pad(flat, ((0, 0), (0, pad)))
+        shards = device_agg.reduce_scatter_mean_flat(
+            mesh, jax.device_put(flat_p, rows))
+        # device (pod, data) owns shard pod*2 + data, on every model index
+        owned = {(d.id, s.index[0].start) for d in mesh.devices.flat
+                 for s in shards.addressable_shards if s.device == d}
+        assert len(owned) == 8 and \
+            len({start for _d, start in owned}) == 4, owned
+        full = np.asarray(device_agg.all_gather_shards(mesh, shards))
+        np.testing.assert_allclose(full[:flat.shape[1]], ref["b"],
                                    rtol=1e-6, atol=1e-7)
         print("DEVICE_AGG_OK")
     """)
@@ -115,14 +119,7 @@ def test_shardmap_trainer_matches_single_device_fedavg():
     """)
 
 
-@pytest.mark.parametrize("gs", [
-    "zero1",
-    pytest.param("zero3", marks=pytest.mark.xfail(
-        reason="pre-existing: zero3 FSDP param update diverges wholesale on "
-               "the jax 0.4.x CPU fake-device mesh (unmasked once "
-               "device_agg imports were fixed); zero1/none agree",
-        strict=False)),
-])
+@pytest.mark.parametrize("gs", ["zero1", "zero3"])
 def test_gspmd_plans_agree(gs):
     """Sharding plans produce the same training numerics as the replicated
     baseline (they only change layout + collective schedule)."""

@@ -57,6 +57,19 @@ def test_fedavg_matches_serverless_streaming_order():
     np.testing.assert_allclose(kernel, serverless, rtol=2e-7, atol=1e-9)
 
 
+@pytest.mark.parametrize("weighted", [False, True])
+def test_fedavg_stream_sum_matches_ref(weighted):
+    """The kernel returns the client-order (weighted) sum — weights read
+    from SMEM per grid step — exactly as the oracle accumulates it."""
+    from repro.kernels.fedavg_stream import fedavg_stream
+    x = jnp.asarray(RNG.standard_normal((5, 64, 128)), jnp.float32)
+    w = jnp.asarray([0.5, 2.0, 1.0, 3.0, 0.25], jnp.float32) \
+        if weighted else None
+    got = fedavg_stream(x, w, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.fedavg_stream_ref(x, w)))
+
+
 @given(n=st.integers(1, 12), blocks=st.integers(1, 5),
        extra=st.integers(0, 4095))
 @settings(max_examples=20, deadline=None)

@@ -6,7 +6,8 @@ engine × schedule × readahead grid; (2) lossy codecs are *deterministic*
 — encode/decode are pure functions, so ``avg_flat`` and ``codec_error``
 are bit-identical across engines, schedules, read-ahead windows and
 arrival permutations; (3) the numpy codec mirrors replay the Pallas
-kernels' f32 op sequence exactly; (4) every modeled platform quantity
+kernels' f32 op sequence (exactly on the tested inputs; the qsgd8 scale
+divide can differ in the last bit elsewhere); (4) every modeled platform quantity
 (upload bytes, GET bytes, billing, feasibility) sees wire sizes, with
 ``pipelined_round_cost`` matching the event sim to float epsilon per
 codec; (5) op *counts* never change — compression moves bytes, not ops.
