@@ -1,4 +1,4 @@
-"""Run every benchmark: one per paper table/figure + kernels + roofline.
+"""Run every benchmark: one per paper table/figure + engines + roofline.
 
 Prints ``name,us_per_call,derived`` CSV rows (plus human-readable tables).
 ``--smoke`` runs a 1-config CI subset (rq3 + event_pipeline) so call-site
@@ -13,7 +13,6 @@ import traceback
 from benchmarks import (
     agg_engine_bench,
     event_pipeline_bench,
-    kernels_bench,
     roofline,
     rq1_idle,
     rq1b_lambda,
@@ -32,7 +31,6 @@ BENCHES = [
     ("rq3_cross_arch (Table VII)", rq3_cross_arch.main),
     ("agg_engine (engines)", agg_engine_bench.main),
     ("event_pipeline (schedules)", event_pipeline_bench.main),
-    ("kernels", kernels_bench.main),
     ("roofline (§Roofline)", roofline.main),
     ("smoke_invariants (CI gate input)", smoke_invariants.main),
 ]

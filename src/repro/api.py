@@ -47,6 +47,7 @@ from repro.serverless.population import (ClientPopulation,
                                          run_population_round)
 from repro.serverless.runtime import FaultPlan, LambdaRuntime
 from repro.store import ObjectStore
+from repro.tracing import span
 
 
 @dataclass(frozen=True)
@@ -314,29 +315,30 @@ class FederatedSession:
             # previous round's cohort, so a resized cohort starts fresh
             # from the runtime cursor instead of inheriting wrong times
             self._client_ready = None
-        result = run_round(
-            self.topology, client_grads, rnd=rnd, store=self.store,
-            runtime=self.runtime, engine=cfg.engine, schedule=cfg.schedule,
-            upload=cfg.resolved_upload(),
-            client_ready_s=self._client_ready,
-            straggler_threshold_s=cfg.straggler_threshold_s,
-            readahead_k=cfg.readahead_k, codec=cfg.codec,
-            track_codec_error=cfg.track_codec_error,
-            faults=cfg.faults, participation_k=cfg.participation_k,
-            deadline_s=cfg.deadline_s, quorum=cfg.quorum,
-            staleness_policy=cfg.staleness_policy,
-            stale_buffer=self.stale_buffer,
-            hedge_factor=cfg.hedge_factor,
-            workers=cfg.workers, host_mesh=cfg.host_mesh,
-            **cfg.round_options())
-        self._observe(result)
-        if not cfg.keep_records:
-            self._compact(rnd)
-            # the per-client read-back array is threaded into the next
-            # round via _client_ready; retaining a copy on every yielded
-            # result would grow O(N·rounds) in callers that keep results
-            result.client_done_s = ()
-        self.rounds_run = max(self.rounds_run, rnd + 1)
+        with span("session.round", rnd=rnd, n=len(client_grads)):
+            result = run_round(
+                self.topology, client_grads, rnd=rnd, store=self.store,
+                runtime=self.runtime, engine=cfg.engine, schedule=cfg.schedule,
+                upload=cfg.resolved_upload(),
+                client_ready_s=self._client_ready,
+                straggler_threshold_s=cfg.straggler_threshold_s,
+                readahead_k=cfg.readahead_k, codec=cfg.codec,
+                track_codec_error=cfg.track_codec_error,
+                faults=cfg.faults, participation_k=cfg.participation_k,
+                deadline_s=cfg.deadline_s, quorum=cfg.quorum,
+                staleness_policy=cfg.staleness_policy,
+                stale_buffer=self.stale_buffer,
+                hedge_factor=cfg.hedge_factor,
+                workers=cfg.workers, host_mesh=cfg.host_mesh,
+                **cfg.round_options())
+            self._observe(result)
+            if not cfg.keep_records:
+                self._compact(rnd)
+                # the per-client read-back array is threaded into the next
+                # round via _client_ready; retaining a copy on every yielded
+                # result would grow O(N·rounds) in callers that keep results
+                result.client_done_s = ()
+            self.rounds_run = max(self.rounds_run, rnd + 1)
         return result
 
     def _population_round(self, rnd: int) -> AggregationResult:

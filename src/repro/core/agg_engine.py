@@ -102,6 +102,7 @@ from repro.core.wire_codec import (EncodedView, WirePayload, decode_eager,
                                    decode_lazy)
 from repro.serverless.event_sim import ReadAheadWindow
 from repro.store import ObjectStore
+from repro.tracing import span
 
 
 # ---------------------------------------------------------------------------
@@ -619,17 +620,21 @@ class BatchedBackend(ExecutionBackend):
         from repro.kernels import ops as kops
         return kops.kernel_mode() is not None
 
-    def _evaluate_pallas(self) -> int:
-        """Dispatch unweighted pending nodes whose inputs are all concrete
-        (no lazy ancestors) to the Pallas fold — one byte-bounded
-        ``fedavg_multi`` per client count, reading inputs in place (no
-        whole-round stack). Returns the number of nodes it folded."""
+    def _kernel_ready(self) -> list:
+        """The pending nodes the Pallas fold takes: unweighted, non-empty,
+        with every input concrete (no lazy ancestors)."""
+        return [nd for nd in self._nodes
+                if nd.out is None and nd.weights is None and nd.size > 0
+                and not any(isinstance(x, LazyAverage) and x.out is None
+                            for x in nd.inputs)]
+
+    def _evaluate_pallas(self, ready: list) -> int:
+        """Dispatch the ``ready`` nodes to the Pallas fold — one
+        byte-bounded ``fedavg_multi`` per client count, reading inputs in
+        place (no whole-round stack). Returns the number of nodes it
+        folded."""
         from repro.kernels import ops as kops
 
-        ready = [nd for nd in self._nodes
-                 if nd.out is None and nd.weights is None and nd.size > 0
-                 and not any(isinstance(x, LazyAverage) and x.out is None
-                             for x in nd.inputs)]
         by_n: dict[int, list[LazyAverage]] = {}
         for nd in ready:
             by_n.setdefault(len(nd.inputs), []).append(nd)
@@ -644,18 +649,19 @@ class BatchedBackend(ExecutionBackend):
         return len(ready)
 
     def end_round(self, store: ObjectStore) -> None:
-        self.kernel_folds = self._evaluate_pallas() \
-            if self._pallas_enabled() else 0
-        _evaluate_nodes(self._nodes, pool=self._pool)
-        for key in store.list():
-            v = store.peek(key)
-            if not isinstance(v, (np.ndarray, bytes, bytearray)) \
-                    and hasattr(v, "materialize"):
-                store.swap(key, v.materialize())
-        # release the round's DAG (it pins every client gradient) so a
-        # backend instance reused across rounds doesn't accumulate them
-        self._nodes = []
-        self._memo = {}
+        ready = self._kernel_ready() if self._pallas_enabled() else []
+        with span("engine.end_round", kernel_folds=len(ready)):
+            self.kernel_folds = self._evaluate_pallas(ready)
+            _evaluate_nodes(self._nodes, pool=self._pool)
+            for key in store.list():
+                v = store.peek(key)
+                if not isinstance(v, (np.ndarray, bytes, bytearray)) \
+                        and hasattr(v, "materialize"):
+                    store.swap(key, v.materialize())
+            # release the round's DAG (it pins every client gradient) so a
+            # backend instance reused across rounds doesn't accumulate them
+            self._nodes = []
+            self._memo = {}
 
 
 class HostMeshBackend(BatchedBackend):

@@ -65,6 +65,7 @@ from repro.serverless.faults import FaultModel, StaleBuffer, StalenessPolicy
 from repro.serverless.runtime import FaultPlan, InvocationRecord, \
     LambdaRuntime
 from repro.store import ObjectStore
+from repro.tracing import span
 
 MB = 1024 * 1024
 
@@ -928,7 +929,8 @@ def run_round(topology: str | Topology,
                          grad_bytes=int(np.asarray(sub[0]).nbytes),
                          limits=limits, options=options, codec=cdc,
                          weights=weights)
-        prog = topo.program(sub, spec, backend)
+        with span("round.program"):
+            prog = topo.program(sub, spec, backend)
         up, put_times = _upload_schedule(
             upload, members, n, rnd, base, client_ready_s,
             prog.uploads[:len(members)], stalls)
@@ -998,9 +1000,10 @@ def run_round(topology: str | Topology,
             sub, prog, up, put_times = build(order, stale_sel)
 
     # -- client uploads: values land immediately, availability is modeled ----
-    for key, value in prog.client_puts:
-        store.put(key, value)
-    _publish_uploads(runtime, put_times)
+    with span("round.upload", puts=len(prog.client_puts)):
+        for key, value in prog.client_puts:
+            store.put(key, value)
+        _publish_uploads(runtime, put_times)
 
     # -- aggregation phases ---------------------------------------------------
     shared: dict = {}
@@ -1015,68 +1018,70 @@ def run_round(topology: str | Topology,
         # stragglers were cut: the barrier only learns membership at T
         prev_end = max(prev_end, deadline_abs)
     first_start = prev_end
-    for phase in prog.phases:
-        ph = runtime.phase(start_s=prev_end if barrier else base)
-        for inv in phase:
-            body = _build_body(backend, store, shared, inv, readahead)
-            # colocated hops have nothing to prefetch and keep the 3x
-            # formula; _alloc_mb clamps the window to the fan-in
-            inv_k = 1 if inv.colocated_in else readahead
-            mem = _alloc_mb(inv.alloc_bytes, limits, inv_k,
-                            fanin=len(inv.in_keys),
-                            wire_in_bytes=inv.wire_in_bytes,
-                            weighted=inv.weights is not None)
-            inv_limits = tier_limits(limits, inv.read_mbps, inv.write_mbps)
-            if barrier:
-                ph.invoke_reliable(
-                    body, fn_name=inv.fn_name, memory_mb=mem,
-                    straggler_threshold_s=straggler_threshold_s,
-                    limits=None if inv_limits is limits else inv_limits)
-            else:
-                # launch on the first available input inside the window
-                # [frontier, frontier + k) — k=1 is the legacy "first
-                # in-index contribution" gating
-                avail = [runtime.avail.time_of(key, base)
-                         for key in inv.in_keys[:inv_k]]
-                launch = max(base, ReadAheadWindow.launch_s(avail, inv_k))
-                hedge_this = hedging and not inv.colocated_in
-                if hedge_this:
-                    was_warm = runtime.is_warm(inv.fn_name)
-                ph.invoke_reliable(
-                    body, fn_name=inv.fn_name, memory_mb=mem,
-                    straggler_threshold_s=straggler_threshold_s,
-                    launch_s=launch, wait_avail=True, out_key=inv.out_key,
-                    limits=None if inv_limits is limits else inv_limits)
-                if hedge_this:
-                    # speculative hedging: replay the aggregator's fault-
-                    # free expected finish off its read-ahead frontier
-                    # (the exact cost-model parity arithmetic); a primary
-                    # whose retry chain overran the hedge threshold races
-                    # a replica on the same keyspace — first finisher
-                    # wins, the loser stays billed
-                    rec = ph.winners[-1]
-                    exp = cm.expected_fold_finish_s(
-                        launch,
-                        [runtime.avail.time_of(key, base)
-                         for key in inv.in_keys],
-                        [inv.alloc_bytes] * len(inv.in_keys),
-                        inv.alloc_bytes, inv_limits, cold=not was_warm,
-                        readahead_k=inv_k,
-                        wire_bytes=None if inv.wire_in_bytes is None
-                        else [inv.wire_in_bytes] * len(inv.in_keys),
-                        decode_s=cdc.decode_cost_s(inv.alloc_bytes)
-                        if inv.wire_in_bytes is not None else 0.0)
-                    thresh = launch + float(hedge_factor) * (exp - launch)
-                    if rec.end_s > thresh:
-                        hedges += 1
-                        hedge_wins += int(ph.hedge_last(
-                            body, fn_name=inv.fn_name + "~hedge",
-                            memory_mb=mem, launch_s=thresh,
-                            out_key=inv.out_key,
-                            limits=None if inv_limits is limits
-                            else inv_limits))
-        prev_end = runtime.finish_phase(ph, barrier=barrier)
-        handles.append(ph)
+    with span("round.phases",
+              invocations=sum(len(phase) for phase in prog.phases)):
+        for phase in prog.phases:
+            ph = runtime.phase(start_s=prev_end if barrier else base)
+            for inv in phase:
+                body = _build_body(backend, store, shared, inv, readahead)
+                # colocated hops have nothing to prefetch and keep the 3x
+                # formula; _alloc_mb clamps the window to the fan-in
+                inv_k = 1 if inv.colocated_in else readahead
+                mem = _alloc_mb(inv.alloc_bytes, limits, inv_k,
+                                fanin=len(inv.in_keys),
+                                wire_in_bytes=inv.wire_in_bytes,
+                                weighted=inv.weights is not None)
+                inv_limits = tier_limits(limits, inv.read_mbps, inv.write_mbps)
+                if barrier:
+                    ph.invoke_reliable(
+                        body, fn_name=inv.fn_name, memory_mb=mem,
+                        straggler_threshold_s=straggler_threshold_s,
+                        limits=None if inv_limits is limits else inv_limits)
+                else:
+                    # launch on the first available input inside the window
+                    # [frontier, frontier + k) — k=1 is the legacy "first
+                    # in-index contribution" gating
+                    avail = [runtime.avail.time_of(key, base)
+                             for key in inv.in_keys[:inv_k]]
+                    launch = max(base, ReadAheadWindow.launch_s(avail, inv_k))
+                    hedge_this = hedging and not inv.colocated_in
+                    if hedge_this:
+                        was_warm = runtime.is_warm(inv.fn_name)
+                    ph.invoke_reliable(
+                        body, fn_name=inv.fn_name, memory_mb=mem,
+                        straggler_threshold_s=straggler_threshold_s,
+                        launch_s=launch, wait_avail=True, out_key=inv.out_key,
+                        limits=None if inv_limits is limits else inv_limits)
+                    if hedge_this:
+                        # speculative hedging: replay the aggregator's fault-
+                        # free expected finish off its read-ahead frontier
+                        # (the exact cost-model parity arithmetic); a primary
+                        # whose retry chain overran the hedge threshold races
+                        # a replica on the same keyspace — first finisher
+                        # wins, the loser stays billed
+                        rec = ph.winners[-1]
+                        exp = cm.expected_fold_finish_s(
+                            launch,
+                            [runtime.avail.time_of(key, base)
+                             for key in inv.in_keys],
+                            [inv.alloc_bytes] * len(inv.in_keys),
+                            inv.alloc_bytes, inv_limits, cold=not was_warm,
+                            readahead_k=inv_k,
+                            wire_bytes=None if inv.wire_in_bytes is None
+                            else [inv.wire_in_bytes] * len(inv.in_keys),
+                            decode_s=cdc.decode_cost_s(inv.alloc_bytes)
+                            if inv.wire_in_bytes is not None else 0.0)
+                        thresh = launch + float(hedge_factor) * (exp - launch)
+                        if rec.end_s > thresh:
+                            hedges += 1
+                            hedge_wins += int(ph.hedge_last(
+                                body, fn_name=inv.fn_name + "~hedge",
+                                memory_mb=mem, launch_s=thresh,
+                                out_key=inv.out_key,
+                                limits=None if inv_limits is limits
+                                else inv_limits))
+            prev_end = runtime.finish_phase(ph, barrier=barrier)
+            handles.append(ph)
     agg_end = prev_end
     if not barrier and late and deadline_abs is not None:
         # a cut round is only known complete at the deadline itself
@@ -1094,11 +1099,12 @@ def run_round(topology: str | Topology,
     # the whole cohort reads the round result back (next round's local
     # training needs it), so read-back op counts stay at cohort size even
     # when the fold covered a subset
-    values = [store.get(key) for key, _nb in prog.readback]
-    if n > 1:
-        for key, _nb in prog.readback:
-            store.account_gets(key, n - 1)
-    avg = np.asarray(prog.collect(values))
+    with span("round.readback", bytes=sum(nb for _k, nb in prog.readback)):
+        values = [store.get(key) for key, _nb in prog.readback]
+        if n > 1:
+            for key, _nb in prog.readback:
+                store.account_gets(key, n - 1)
+        avg = np.asarray(prog.collect(values))
     member_done = _readback_times(sched, runtime, upload, up,
                                   prog.readback, agg_end)
     if order == list(range(n)):
@@ -1226,13 +1232,15 @@ def sharded_client_uploads(client_grads, rnd: int, plan: PartitionPlan,
     ``(client_puts, uploads, shard_bytes, wire_shard_bytes)``."""
     codec = get_codec(codec)
     m = plan.n_shards
-    shard_bytes = [s * 4 for s in plan.shard_sizes()]
+    sizes = plan.shard_sizes()
+    shard_bytes = [s * 4 for s in sizes]
     wire_bytes = [codec.wire_bytes(b) for b in shard_bytes]
     puts, uploads = [], []
     for i, g in enumerate(client_grads):
         flat = np.asarray(g, np.float32)
-        puts.extend((k_client_shard(rnd, i, j), codec.encode(sh))
-                    for j, sh in enumerate(backend.shard_values(flat, plan)))
+        for j, sh in enumerate(backend.shard_values(flat, plan)):
+            with span("codec.encode", elems=int(sizes[j])):
+                puts.append((k_client_shard(rnd, i, j), codec.encode(sh)))
         uploads.append([(k_client_shard(rnd, i, j), wire_bytes[j])
                         for j in range(m)])
     return tuple(puts), tuple(uploads), shard_bytes, wire_bytes
@@ -1301,12 +1309,14 @@ def full_grad_uploads(client_grads, rnd, codec: WireCodec | None = None):
     codec = get_codec(codec)
     grad_bytes = int(np.asarray(client_grads[0]).nbytes)
     wire_grad_bytes = codec.wire_bytes(grad_bytes)
-    puts = tuple((k_client_grad(rnd, i),
-                  codec.encode(np.asarray(g, np.float32)))
-                 for i, g in enumerate(client_grads))
+    puts = []
+    for i, g in enumerate(client_grads):
+        flat = np.asarray(g, np.float32)
+        with span("codec.encode", elems=int(flat.size)):
+            puts.append((k_client_grad(rnd, i), codec.encode(flat)))
     uploads = tuple([(k_client_grad(rnd, i), wire_grad_bytes)]
                     for i in range(len(client_grads)))
-    return puts, uploads, grad_bytes, wire_grad_bytes
+    return tuple(puts), uploads, grad_bytes, wire_grad_bytes
 
 
 @register_topology("lambda_fl")

@@ -73,4 +73,5 @@ def fedavg_stream(stacked: jax.Array, weights: jax.Array | None = None, *,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.float32),
         interpret=interpret,
+        name="fedavg_stream",
     )(*args)

@@ -21,6 +21,7 @@ from repro.kernels import fused_sgd as _sgd
 from repro.kernels import quantize as _q
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import topk_sparsify as _tk
+from repro.tracing import span
 
 LANES = 128
 
@@ -161,33 +162,47 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
     windows = fold_windows(offsets[-1], n, fold_budget_bytes(), parts,
                            block_rows)
 
-    def run(a: int, b: int) -> None:
+    def run(index: int, a: int, b: int) -> None:
         tile = block_rows * LANES
         cols = -(-(b - a) // tile) * tile
-        buf = np.empty((n, cols), np.float32)
-        buf[:, b - a:] = 0.0
-        segs = []
-        for j, (off, l) in enumerate(zip(offsets, lengths)):
-            lo, hi = max(a, off), min(b, off + l)
-            if lo < hi:
-                segs.append((j, lo, hi))
-                for i, row in enumerate(stacks[j]):
-                    buf[i, lo - a:hi - a] = read(row, lo - off, hi - off)
-        tiles = jax.device_put(buf.reshape(n, -1, LANES))
-        del buf
-        total = np.asarray(_fold_sum(tiles, w_dev, block_rows, interpret))
-        del tiles
-        total = total.reshape(-1)
-        for j, lo, hi in segs:
-            off = offsets[j]
-            np.divide(total[lo - a:hi - a], div,
-                      out=outs[j][lo - off:hi - off])
+        with span("fold.window", index=index, n=n, cols=cols):
+            with span("fold.fill", bytes=n * cols * 4):
+                buf = np.empty((n, cols), np.float32)
+                buf[:, b - a:] = 0.0
+                segs = []
+                for j, (off, l) in enumerate(zip(offsets, lengths)):
+                    lo, hi = max(a, off), min(b, off + l)
+                    if lo < hi:
+                        segs.append((j, lo, hi))
+                        for i, row in enumerate(stacks[j]):
+                            buf[i, lo - a:hi - a] = read(row, lo - off,
+                                                         hi - off)
+            # The two waits below give fold.h2d and fold.kernel their
+            # meaning. They cost at most one dispatch latency per window:
+            # the kernel cannot start before its window has arrived, and
+            # np.asarray blocks on the sum anyway.
+            with span("fold.h2d", bytes=n * cols * 4):
+                tiles = jax.device_put(buf.reshape(n, -1, LANES))
+                tiles.block_until_ready()
+            del buf
+            with span("fold.kernel"):
+                total = _fold_sum(tiles, w_dev, block_rows, interpret)
+                total.block_until_ready()
+            del tiles
+            with span("fold.d2h", bytes=cols * 4):
+                total = np.asarray(total).reshape(-1)
+            with span("fold.divide"):
+                for j, lo, hi in segs:
+                    off = offsets[j]
+                    np.divide(total[lo - a:hi - a], div,
+                              out=outs[j][lo - off:hi - off])
 
+    tasks = [(k, a, b) for k, (a, b) in enumerate(windows)]
     if interpret:
-        pool.map(run, windows)
+        pool.map(run, tasks)
     else:
-        for a, b in windows:
-            run(a, b)
+        for task in tasks:
+            run(*task)
     return outs
 
 

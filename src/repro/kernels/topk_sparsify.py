@@ -55,4 +55,5 @@ def topk_sparsify(x: jax.Array, k_per_block: int, *, block_rows: int = 32,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.float32),
         interpret=interpret,
+        name="topk_sparsify",
     )(x)

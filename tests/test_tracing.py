@@ -1,0 +1,145 @@
+"""The program's tracing spans (``repro.tracing``).
+
+A span records only under a ``jax.profiler`` trace, carries its counts as
+the event's stats, and reaches no value: a traced round is bit-identical
+to an untraced one. The Pallas fold runs in interpret mode here
+(``REPRO_AGG_PALLAS=1``), so the fold-window spans are those the chip
+path emits.
+"""
+from __future__ import annotations
+
+import glob
+
+import jax
+import numpy as np
+import pytest
+
+from repro.api import FederatedSession, SessionConfig
+from repro.kernels import ops
+from repro.tracing import PREFIX, span
+
+N, ELEMS, M, WORKERS = 3, 10_000, 2, 2
+TILE = 32 * ops.LANES
+
+#: child span -> the span it runs inside
+PARENT = {
+    "round.program": "session.round",
+    "codec.encode": "round.program",
+    "round.upload": "session.round",
+    "round.phases": "session.round",
+    "engine.end_round": "session.round",
+    "fold.window": "engine.end_round",
+    "fold.fill": "fold.window",
+    "fold.h2d": "fold.window",
+    "fold.kernel": "fold.window",
+    "fold.d2h": "fold.window",
+    "fold.divide": "fold.window",
+    "round.readback": "session.round",
+}
+FOLD_PARTS = ["fold.fill", "fold.h2d", "fold.kernel", "fold.d2h", "fold.divide"]
+
+
+def test_span_yields_none_without_a_profiler():
+    with span("fold.h2d", bytes=123) as got:
+        assert got is None
+    with span("session.round") as got:
+        assert got is None
+
+
+def _grads(seed: int) -> list:
+    rng = np.random.default_rng(seed)
+    return [rng.standard_normal(ELEMS, dtype=np.float32) for _ in range(N)]
+
+
+def _session(codec: str) -> FederatedSession:
+    return FederatedSession(SessionConfig(n_shards=M, codec=codec, workers=WORKERS,
+                                          track_codec_error=False))
+
+
+def _program_events(log_dir: str) -> list:
+    """(name without the prefix, start, end, stats, thread) of each span."""
+    from jax.profiler import ProfileData
+
+    path = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for k, ln in enumerate(plane.lines):
+            out.extend((e.name[len(PREFIX):], e.start_ns, e.end_ns, dict(e.stats),
+                        (plane.name, k))
+                       for e in ln.events if e.name.startswith(PREFIX))
+    return out
+
+
+@pytest.fixture(scope="module", params=["identity", "topk"])
+def rounds(request, tmp_path_factory):
+    """Round 1 of two sessions on the same gradients: untraced, and traced."""
+    codec = request.param
+    grads = [_grads(0), _grads(1)]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_AGG_PALLAS", "1")
+        plain, traced = _session(codec), _session(codec)
+        plain.round(grads[0])
+        traced.round(grads[0])
+        want = plain.round(grads[1])
+        log_dir = str(tmp_path_factory.mktemp(f"trace-{codec}"))
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        with jax.profiler.trace(log_dir, profiler_options=opts):
+            got = traced.round(grads[1])
+    return plain, want, traced, got, _program_events(log_dir)
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_traced_round_emits_every_span_nested(rounds):
+    *_, events = rounds
+    assert {e[0] for e in events} == {"session.round", *PARENT}
+    for name, parent in PARENT.items():
+        for child in _named(events, name):
+            same_thread = name.startswith("fold.") and name != "fold.window"
+            assert any(_inside(child, p) and (not same_thread or p[4] == child[4])
+                       for p in _named(events, parent)), (name, parent)
+    for w in _named(events, "fold.window"):
+        parts = [e for e in events if e[0] in FOLD_PARTS and e[4] == w[4]
+                 and _inside(e, w)]
+        assert [e[0] for e in sorted(parts, key=lambda e: e[1])] == FOLD_PARTS
+
+
+def test_span_counts_match_the_shapes(rounds):
+    *_, events = rounds
+    (sess,) = _named(events, "session.round")
+    assert sess[3] == {"rnd": 1, "n": N}
+    encodes = _named(events, "codec.encode")
+    assert len(encodes) == N * M and all(e[3] == {"elems": ELEMS // M} for e in encodes)
+    assert _named(events, "round.upload")[0][3] == {"puts": N * M}
+    assert _named(events, "round.phases")[0][3] == {"invocations": M}
+    assert _named(events, "engine.end_round")[0][3] == {"kernel_folds": M}
+    assert _named(events, "round.readback")[0][3] == {"bytes": ELEMS * 4}
+    windows = ops.fold_windows(ELEMS, N, None, WORKERS)
+    cols = [-(-(b - a) // TILE) * TILE for a, b in windows]
+    got = sorted((e[3]["index"], e[3]["n"], e[3]["cols"])
+                 for e in _named(events, "fold.window"))
+    assert got == [(k, N, c) for k, c in enumerate(cols)]
+    for name, per_window in [("fold.fill", N * 4), ("fold.h2d", N * 4), ("fold.d2h", 4)]:
+        assert sorted(e[3]["bytes"] for e in _named(events, name)) \
+            == sorted(c * per_window for c in cols)
+    assert all(e[3] == {} for e in events if e[0] in ("fold.kernel", "fold.divide",
+                                                      "round.program"))
+
+
+def test_traced_round_is_bit_identical(rounds):
+    plain, want, traced, got, _ = rounds
+    assert np.array_equal(got.avg_flat, want.avg_flat)
+    assert got.kernel_folds == want.kernel_folds == M
+    assert (got.wall_clock_s, got.phases_s) == (want.wall_clock_s, want.phases_s)
+    assert (got.puts, got.gets) == (want.puts, want.gets)
+    assert got.records == want.records
+    assert traced.total_cost() == plain.total_cost()
+    assert traced.summary() == plain.summary()
