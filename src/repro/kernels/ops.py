@@ -8,6 +8,8 @@ kernels and the numpy mirrors of the aggregation engine and wire codecs.
 """
 from __future__ import annotations
 
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Callable, Sequence
 
@@ -21,7 +23,8 @@ from repro.kernels import fused_sgd as _sgd
 from repro.kernels import quantize as _q
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import topk_sparsify as _tk
-from repro.tracing import span
+from repro.launch.hostenv import host_timer
+from repro.tracing import span, tally
 
 LANES = 128
 
@@ -62,8 +65,22 @@ def _from_tiles(tiles: jax.Array, l: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# fold: byte-bounded launches of the fedavg_stream kernel
+# fold: byte-bounded launches of the fedavg_stream kernel, streamed through
+# two reused host staging buffers
 # ---------------------------------------------------------------------------
+
+#: Host bytes of one fold window's (N, cols) f32 input, and of each of the
+#: two staging buffers that the windows stream through. Chosen from a sweep
+#: of 128 MB to 900 MB windows on a TPU v5e (PERF.md, section 6).
+STAGING_BYTES = 512 << 20
+
+#: the two staging buffers, allocated on first use and kept for the process
+_staging: list = [None, None]
+#: one streamed call at a time: every call shares the two buffers
+_staging_lock = threading.Lock()
+#: the thread that runs the odd windows of a streamed call
+_lane: ThreadPoolExecutor | None = None
+
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _fold_sum(tiles, weights, block_rows, interpret):
@@ -72,10 +89,10 @@ def _fold_sum(tiles, weights, block_rows, interpret):
 
 
 def fold_budget_bytes() -> int | None:
-    """Bytes one fold launch may hold on the default device: half of what
-    its allocator has free (``memory_stats()``), so a launch's input and
-    output fit beside whatever the previous launch is still releasing.
-    None where the backend reports no limit (the CPU)."""
+    """Bytes the fold's launches in flight may hold together on the default
+    device: half of what its allocator has free (``memory_stats()``), so
+    they fit beside whatever earlier launches are still releasing. None
+    where the backend reports no limit (the CPU)."""
     stats = jax.devices()[0].memory_stats()
     if not stats or "bytes_limit" not in stats:
         return None
@@ -87,9 +104,11 @@ def fold_windows(total: int, n: int, budget_bytes: int | None,
                  parts: int = 1, block_rows: int = 32) -> list:
     """Cut ``total`` fold columns of an N-client round into launch windows.
 
-    Each window is ``[start, stop)`` with a tile-aligned start; its
-    launch holds an (N, cols) f32 input and a (cols,) f32 output, cols
-    padded to the kernel tile, within ``budget_bytes`` (None = no bound).
+    Each window is ``[start, stop)`` with a tile-aligned start. Its (N,
+    cols) f32 input, cols padded to the kernel tile, fits one staging
+    buffer of :data:`STAGING_BYTES` (or is one tile, where N rows of a
+    tile need more), and two launches in flight, each an (N, cols) input
+    and a (cols,) output, fit ``budget_bytes`` (None = no device bound).
     Windows are as equal as the tiles allow and at least ``parts`` in
     number when there are enough tiles (the interpret-mode fold pool).
     """
@@ -97,16 +116,44 @@ def fold_windows(total: int, n: int, budget_bytes: int | None,
     n_tiles = -(-total // tile)
     if n_tiles == 0:
         return []
-    cap = n_tiles
+    cap = max(1, STAGING_BYTES // (4 * n * tile))
     if budget_bytes is not None:
-        cap = budget_bytes // (4 * (n + 1) * tile)
-        if cap < 1:
+        fits = budget_bytes // (2 * 4 * (n + 1) * tile)
+        if fits < 1:
             raise ValueError(
-                f"one {n}-client fold tile needs {4 * (n + 1) * tile} B, "
-                f"more than the device budget of {budget_bytes} B")
+                f"two {n}-client fold tiles in flight need "
+                f"{2 * 4 * (n + 1) * tile} B, more than the device budget "
+                f"of {budget_bytes} B")
+        cap = min(cap, fits)
     n_win = max(-(-n_tiles // cap), min(parts, n_tiles))
     per = -(-n_tiles // n_win) * tile
     return [(s, min(s + per, total)) for s in range(0, total, per)]
+
+
+def _staging_buffer(slot: int, elems: int) -> tuple[np.ndarray, int]:
+    """Staging buffer ``slot`` (0 or 1) with room for ``elems`` f32, and 1
+    if it was allocated now (first use, or a window larger than it)."""
+    buf = _staging[slot]
+    if buf is not None and buf.size >= elems:
+        return buf, 0
+    buf = _staging[slot] = np.empty(max(elems, STAGING_BYTES // 4),
+                                    np.float32)
+    return buf, 1
+
+
+def _second_lane() -> ThreadPoolExecutor:
+    global _lane
+    if _lane is None:
+        _lane = ThreadPoolExecutor(1, thread_name_prefix="repro-stage")
+    return _lane
+
+
+def _overlapped(times: list) -> int:
+    """The windows whose fill ran while an earlier window was in flight,
+    from ``times``: per window [fill start, fill end, device_put, sum back
+    on the host]."""
+    return len([k for k, (fs, fe, _, _) in enumerate(times)
+                if any(put < fe and fs < back for _, _, put, back in times[:k])])
 
 
 def _slice_rows(x, s: int, e: int):
@@ -124,19 +171,25 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
     an (N, L_j) array, or a sequence of N row objects that ``read(row,
     start, stop)`` slices (default: ``row[start:stop]``) — every stack
     holding the same N clients in the same order. The stacks' columns
-    are laid end to end and cut by :func:`fold_windows` so that each
-    launch fits :func:`fold_budget_bytes`; each window is built on the
-    host, moved to the device, folded and copied back before the next is
-    built. The kernel returns sums, and the mean is one f32 divide on the
-    host — the numpy evaluator's op — so an unweighted result is
+    are laid end to end and cut by :func:`fold_windows`; each window is
+    filled on the host, moved to the device, folded, copied back and
+    divided. The kernel returns sums, and the mean is one f32 divide on
+    the host — the numpy evaluator's op — so an unweighted result is
     bit-identical to the streaming reference, and averaging being
     element-wise, to any other windowing.
 
-    ``workers`` > 1 runs windows on the host fold pool — interpret mode
-    only, where launches are host-bound; on TPU windows run one at a
-    time so only one holds device memory.
+    The windows stream through two host staging buffers kept for the
+    process: the even windows through one on the calling thread, the odd
+    through the other on a second thread. Window k+1 is filled, its
+    column spans on the fold pool of ``workers``, while window k moves to
+    the device and folds. Fills run in window order, and so do transfers;
+    a buffer is refilled only after the device array made from it is
+    ready, so at most two windows are on the device at once. With
+    ``workers`` > 1 in interpret mode, where launches are host-bound,
+    windows instead run on the fold pool, each in fresh memory.
 
-    Returns a list of (L_j,) f32 means, one per input stack.
+    Returns a list of (L_j,) f32 means, one per input stack, each newly
+    allocated.
     """
     if interpret is None:
         interpret = _use_interpret()
@@ -156,53 +209,102 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
         w_host = np.asarray(weights, np.float32)
         w_dev, div = jnp.asarray(w_host), np.float32(w_host.sum())
     outs = [np.empty(l, np.float32) for l in lengths]
-    from repro.core.fold_pool import get_pool
+    from repro.core.fold_pool import PARALLEL_MIN_ELEMS, get_pool, partition
     pool = get_pool(workers)
-    parts = pool.workers if interpret else 1
-    windows = fold_windows(offsets[-1], n, fold_budget_bytes(), parts,
-                           block_rows)
+    fan_out = interpret and pool.workers > 1
+    windows = fold_windows(offsets[-1], n, fold_budget_bytes(),
+                           pool.workers if fan_out else 1, block_rows)
+    tile = block_rows * LANES
+    cols = [-(-(b - a) // tile) * tile for a, b in windows]
+    # fills run in window order, and so do transfers: window k waits for k - 1
+    filled = [threading.Event() for _ in windows]
+    arrived = [threading.Event() for _ in windows]
+    times = [[0.0] * 4 for _ in windows]
 
-    def run(index: int, a: int, b: int) -> None:
-        tile = block_rows * LANES
-        cols = -(-(b - a) // tile) * tile
-        with span("fold.window", index=index, n=n, cols=cols):
-            with span("fold.fill", bytes=n * cols * 4):
-                buf = np.empty((n, cols), np.float32)
-                buf[:, b - a:] = 0.0
-                segs = []
-                for j, (off, l) in enumerate(zip(offsets, lengths)):
-                    lo, hi = max(a, off), min(b, off + l)
-                    if lo < hi:
-                        segs.append((j, lo, hi))
-                        for i, row in enumerate(stacks[j]):
-                            buf[i, lo - a:hi - a] = read(row, lo - off,
-                                                         hi - off)
-            # The two waits below give fold.h2d and fold.kernel their
-            # meaning. They cost at most one dispatch latency per window:
-            # the kernel cannot start before its window has arrived, and
-            # np.asarray blocks on the sum anyway.
-            with span("fold.h2d", bytes=n * cols * 4):
-                tiles = jax.device_put(buf.reshape(n, -1, LANES))
-                tiles.block_until_ready()
-            del buf
-            with span("fold.kernel"):
-                total = _fold_sum(tiles, w_dev, block_rows, interpret)
-                total.block_until_ready()
-            del tiles
-            with span("fold.d2h", bytes=cols * 4):
-                total = np.asarray(total).reshape(-1)
-            with span("fold.divide"):
-                for j, lo, hi in segs:
-                    off = offsets[j]
-                    np.divide(total[lo - a:hi - a], div,
-                              out=outs[j][lo - off:hi - off])
+    def fill_cols(buf, a: int, lo: int, hi: int) -> None:
+        """Round columns [a + lo, a + hi) into columns [lo, hi) of buf."""
+        for off, l, stack in zip(offsets, lengths, stacks):
+            s, e = max(a + lo, off), min(a + hi, off + l)
+            if s < e:
+                for i, row in enumerate(stack):
+                    buf[i, s - a:e - a] = read(row, s - off, e - off)
 
-    tasks = [(k, a, b) for k, (a, b) in enumerate(windows)]
-    if interpret:
-        pool.map(run, tasks)
-    else:
-        for task in tasks:
-            run(*task)
+    def fill_spans(width: int) -> list:
+        if fan_out or n * width < PARALLEL_MIN_ELEMS:
+            return [(0, width)]
+        return partition(width, pool.workers, LANES)
+
+    def run(k: int, buf) -> None:
+        a, b = windows[k]
+        try:
+            with span("fold.window", index=k, n=n, cols=cols[k]):
+                if k:
+                    filled[k - 1].wait()
+                times[k][0] = host_timer()
+                with span("fold.fill", bytes=n * cols[k] * 4):
+                    buf[:, b - a:] = 0.0
+                    pool.map(partial(fill_cols, buf, a), fill_spans(b - a))
+                times[k][1] = host_timer()
+                filled[k].set()
+                if k:
+                    arrived[k - 1].wait()
+                times[k][2] = host_timer()
+                # The waits below give fold.h2d and fold.kernel their
+                # meaning, and the first frees the buffer for its next
+                # fill: whatever host-buffer semantics device_put has, a
+                # ready array no longer reads its host source.
+                with span("fold.h2d", bytes=n * cols[k] * 4):
+                    tiles = jax.device_put(buf.reshape(n, -1, LANES))
+                    tiles.block_until_ready()
+                arrived[k].set()
+                with span("fold.kernel"):
+                    total = _fold_sum(tiles, w_dev, block_rows, interpret)
+                    total.block_until_ready()
+                del tiles
+                with span("fold.d2h", bytes=cols[k] * 4):
+                    total = np.asarray(total).reshape(-1)
+                times[k][3] = host_timer()
+                with span("fold.divide"):
+                    for off, l, out in zip(offsets, lengths, outs):
+                        lo, hi = max(a, off), min(b, off + l)
+                        if lo < hi:
+                            np.divide(total[lo - a:hi - a], div,
+                                      out=out[lo - off:hi - off])
+        except BaseException:
+            for ev in filled + arrived:      # let no other window wait
+                ev.set()
+            raise
+
+    def fresh(k: int) -> None:
+        run(k, np.empty((n, cols[k]), np.float32))
+
+    def lane(slot: int) -> int:
+        """Windows slot, slot + 2, ... through staging buffer ``slot``;
+        returns the buffers it allocated."""
+        mine = range(slot, len(windows), 2)
+        staging, allocs = _staging_buffer(slot, n * max(cols[k] for k in mine))
+        for k in mine:
+            run(k, staging[:n * cols[k]].reshape(n, cols[k]))
+        return allocs
+
+    with tally("fold.stream", windows=len(windows), staging_bytes=0,
+               allocs=0, overlapped=0) as counts:
+        if fan_out:
+            pool.map(fresh, [(k,) for k in range(len(windows))])
+            counts["allocs"] = len(windows)
+        elif windows:
+            with _staging_lock:
+                other = (_second_lane().submit(lane, 1) if len(windows) > 1
+                         else None)
+                allocs = 0
+                try:
+                    allocs = lane(0)
+                finally:
+                    # the second lane leaves its buffer before the lock goes
+                    allocs += other.result() if other else 0
+                counts["allocs"] = allocs
+                counts["staging_bytes"] = _staging[0].nbytes
+        counts["overlapped"] = _overlapped(times)
     return outs
 
 

@@ -265,26 +265,129 @@ def test_fedavg_multi_matches_per_shard_calls():
         assert np.array_equal(got, single)
 
 
-@pytest.mark.parametrize("tiles_per_launch", [1, 2, 5])
-def test_fedavg_multi_byte_bounded_windows(monkeypatch, tiles_per_launch):
-    """A device budget of a few kernel tiles cuts the round's columns into
-    many launches — across shard boundaries — and every cut stays
-    bit-identical to the streaming f32 left-fold + one f32 divide."""
+def _left_fold_mean(stack):
+    acc = stack[0].copy()
+    for row in stack[1:]:
+        acc += row
+    return acc / np.float32(len(stack))
+
+
+def _fold_stream_counts(call) -> list:
+    """Run ``call()`` under a profiler trace; the stats of each
+    ``repro.fold.stream`` span it recorded, in order."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with tempfile.TemporaryDirectory() as log_dir:
+        with jax.profiler.trace(log_dir, profiler_options=opts):
+            call()
+        path = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)[0]
+        events = [e for plane in ProfileData.from_file(path).planes
+                  for ln in plane.lines for e in ln.events
+                  if e.name == "repro.fold.stream"]
+        return [dict(e.stats) for e in sorted(events, key=lambda e: e.start_ns)]
+
+
+@pytest.mark.parametrize(
+    "bound, tiles_per_launch",
+    [pytest.param("budget", t, id=str(t)) for t in (1, 2, 5)]
+    + [pytest.param("staging", t, id=f"staging-{t}") for t in (1, 2, 5)])
+def test_fedavg_multi_byte_bounded_windows(monkeypatch, bound,
+                                           tiles_per_launch):
+    """A device budget or a staging size of a few kernel tiles cuts the
+    round's columns into many tile-aligned launches — across shard
+    boundaries — and every cut stays bit-identical to the streaming f32
+    left-fold + one f32 divide. The budget holds two launches in flight;
+    a staging buffer holds one window's (N, cols) input."""
     from repro.kernels import ops
     n, tile = 4, 32 * 128
-    budget = 4 * (n + 1) * tile * tiles_per_launch
-    monkeypatch.setattr(ops, "fold_budget_bytes", lambda: budget)
+    if bound == "budget":
+        budget = 2 * 4 * (n + 1) * tile * tiles_per_launch
+        monkeypatch.setattr(ops, "fold_budget_bytes", lambda: budget)
+    else:
+        monkeypatch.setattr(ops, "STAGING_BYTES",
+                            4 * n * tile * tiles_per_launch)
     rng = np.random.default_rng(12)
     stacks = [rng.standard_normal((n, l)).astype(np.float32)
               for l in (5_000, 4_096, 3, 9_001)]
-    windows = ops.fold_windows(sum(s.shape[1] for s in stacks), n, budget)
+    windows = ops.fold_windows(sum(s.shape[1] for s in stacks), n,
+                               ops.fold_budget_bytes())
     assert len(windows) >= -(-5 // tiles_per_launch)
-    assert all(b - a <= tile * tiles_per_launch for a, b in windows)
+    assert all(a % tile == 0 and b - a <= tile * tiles_per_launch
+               for a, b in windows)
+    assert all(4 * n * -(-(b - a) // tile) * tile <= ops.STAGING_BYTES
+               for a, b in windows)
     for stack, got in zip(stacks, ops.fedavg_multi(stacks, workers=1)):
-        acc = stack[0].copy()
-        for row in stack[1:]:
-            acc += row
-        assert np.array_equal(got, acc / np.float32(n))
+        assert np.array_equal(got, _left_fold_mean(stack))
+
+
+@pytest.mark.parametrize("first, second", [
+    ((6, (9_000, 5_000)), (3, (4_100, 7))),
+    ((3, (4_100, 7)), (6, (9_000, 5_000))),
+], ids=["shrink", "grow"])
+def test_fedavg_multi_reuses_staging_without_stale_data(monkeypatch, first,
+                                                        second):
+    """Two calls in a row stream through the same two staging buffers, the
+    second with another N, other lengths and other data: no row or pad
+    column left by the first reaches the second's means, and the second
+    allocates no staging buffer."""
+    from repro.kernels import ops
+    tile = 32 * 128
+    monkeypatch.setattr(ops, "STAGING_BYTES", 4 * 6 * tile * 2)
+    rng = np.random.default_rng(13)
+    calls = [[rng.standard_normal((n, l)).astype(np.float32) for l in lens]
+             for n, lens in (first, second)]
+    got = []
+    counts = _fold_stream_counts(
+        lambda: got.extend(ops.fedavg_multi(c, workers=1) for c in calls))
+    for stacks, means in zip(calls, got):
+        for stack, mean in zip(stacks, means):
+            assert np.array_equal(mean, _left_fold_mean(stack))
+    assert [c["windows"] for c in counts] == [
+        len(ops.fold_windows(sum(s.shape[1] for s in stacks), len(stacks[0]),
+                             None)) for stacks in calls]
+    assert counts[0]["allocs"] <= 2 and counts[1]["allocs"] == 0
+    assert all(c["staging_bytes"] >= ops.STAGING_BYTES for c in counts)
+
+
+def test_fedavg_multi_fills_the_next_window_while_one_is_in_flight(
+        monkeypatch):
+    """With fills and launches of 0.1 s or more, and the kernel compiled,
+    every window's fill after the first runs while an earlier window is
+    on its way or folding."""
+    import time
+
+    from repro.kernels import ops
+    n, tile = 3, 32 * 128
+    monkeypatch.setattr(ops, "STAGING_BYTES", 4 * n * tile)
+    fold_sum = ops._fold_sum
+
+    def slow_fold_sum(*args):
+        time.sleep(0.1)
+        return fold_sum(*args)
+
+    monkeypatch.setattr(ops, "_fold_sum", slow_fold_sum)
+    stack = np.random.default_rng(14).standard_normal(
+        (n, 4 * tile)).astype(np.float32)
+
+    def slow_read(row, s, e):
+        time.sleep(0.05)
+        return row[s:e]
+
+    ops.fedavg_multi([stack], workers=1)          # compile the kernel
+    got = []
+    (counts,) = _fold_stream_counts(
+        lambda: got.extend(ops.fedavg_multi([stack], workers=1,
+                                            read=slow_read)))
+    assert np.array_equal(got[0], _left_fold_mean(stack))
+    assert counts["windows"] == 4
+    assert counts["overlapped"] == 3
 
 
 def test_fold_windows_refuses_a_tile_over_budget():

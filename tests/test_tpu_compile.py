@@ -108,14 +108,17 @@ def test_topk_sparsify_compiles_at_vgg16_shard(one_chip):
 
 def test_largest_fold_window_fits_one_v5e(one_chip):
     """The biggest launch the batched engine forms for a VGG-16 N=20
-    round on an idle chip compiles and fits the chip's HBM."""
+    round on an idle chip compiles, fills at most one staging buffer, and
+    two such launches in flight fit half of the chip's HBM."""
     windows = ops.fold_windows(VGG16_ELEMS, N, V5E_BYTES_LIMIT // 2)
     assert len(windows) > 1           # one launch would not fit
     cols = max(_ceil_div(b - a, TILE) * TILE for a, b in windows)
+    assert 4 * N * cols <= ops.STAGING_BYTES
     x = jax.ShapeDtypeStruct((N, cols // 128, 128), jnp.float32,
                              sharding=one_chip)
     c = _compile(lambda x: ops._fold_sum(x, None, 32, False), x)
-    assert _fits(c, V5E_BYTES_LIMIT // 2) >= 4 * (N + 1) * cols
+    assert 2 * _fits(c) <= V5E_BYTES_LIMIT // 2
+    assert _fits(c) >= 4 * (N + 1) * cols
 
 
 def test_mesh_reduce_scatter_compiles_on_four_chips(topo):

@@ -3,8 +3,9 @@
 A span records only under a ``jax.profiler`` trace, carries its counts as
 the event's stats, and reaches no value: a traced round is bit-identical
 to an untraced one. The Pallas fold runs in interpret mode here
-(``REPRO_AGG_PALLAS=1``), so the fold-window spans are those the chip
-path emits.
+(``REPRO_AGG_PALLAS=1``): with one worker, through the staging buffers
+and the two window lanes the chip path runs, in windows of one tile; with
+two, on the fold pool.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ PARENT = {
     "round.upload": "session.round",
     "round.phases": "session.round",
     "engine.end_round": "session.round",
-    "fold.window": "engine.end_round",
+    "fold.stream": "engine.end_round",
+    "fold.window": "fold.stream",
     "fold.fill": "fold.window",
     "fold.h2d": "fold.window",
     "fold.kernel": "fold.window",
@@ -51,8 +53,8 @@ def _grads(seed: int) -> list:
     return [rng.standard_normal(ELEMS, dtype=np.float32) for _ in range(N)]
 
 
-def _session(codec: str) -> FederatedSession:
-    return FederatedSession(SessionConfig(n_shards=M, codec=codec, workers=WORKERS,
+def _session(codec: str, workers: int) -> FederatedSession:
+    return FederatedSession(SessionConfig(n_shards=M, codec=codec, workers=workers,
                                           track_codec_error=False))
 
 
@@ -70,14 +72,23 @@ def _program_events(log_dir: str) -> list:
     return out
 
 
-@pytest.fixture(scope="module", params=["identity", "topk"])
+@pytest.fixture(scope="module", params=[
+    pytest.param(("identity", WORKERS), id="identity"),
+    pytest.param(("topk", WORKERS), id="topk"),
+    pytest.param(("identity", 1), id="identity-staged"),
+    pytest.param(("topk", 1), id="topk-staged"),
+])
 def rounds(request, tmp_path_factory):
-    """Round 1 of two sessions on the same gradients: untraced, and traced."""
-    codec = request.param
+    """Round 1 of two sessions on the same gradients, untraced and traced,
+    and the fold windows of the round."""
+    codec, workers = request.param
     grads = [_grads(0), _grads(1)]
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("REPRO_AGG_PALLAS", "1")
-        plain, traced = _session(codec), _session(codec)
+        if workers == 1:
+            mp.setattr(ops, "STAGING_BYTES", 4 * N * TILE)
+        windows = ops.fold_windows(ELEMS, N, None, workers)
+        plain, traced = _session(codec, workers), _session(codec, workers)
         plain.round(grads[0])
         traced.round(grads[0])
         want = plain.round(grads[1])
@@ -87,7 +98,7 @@ def rounds(request, tmp_path_factory):
         opts.host_tracer_level = 1
         with jax.profiler.trace(log_dir, profiler_options=opts):
             got = traced.round(grads[1])
-    return plain, want, traced, got, _program_events(log_dir)
+    return plain, want, traced, got, (workers, windows), _program_events(log_dir)
 
 
 def _named(events, name):
@@ -113,7 +124,7 @@ def test_traced_round_emits_every_span_nested(rounds):
 
 
 def test_span_counts_match_the_shapes(rounds):
-    *_, events = rounds
+    *_, (_, windows), events = rounds
     (sess,) = _named(events, "session.round")
     assert sess[3] == {"rnd": 1, "n": N}
     encodes = _named(events, "codec.encode")
@@ -122,7 +133,6 @@ def test_span_counts_match_the_shapes(rounds):
     assert _named(events, "round.phases")[0][3] == {"invocations": M}
     assert _named(events, "engine.end_round")[0][3] == {"kernel_folds": M}
     assert _named(events, "round.readback")[0][3] == {"bytes": ELEMS * 4}
-    windows = ops.fold_windows(ELEMS, N, None, WORKERS)
     cols = [-(-(b - a) // TILE) * TILE for a, b in windows]
     got = sorted((e[3]["index"], e[3]["n"], e[3]["cols"])
                  for e in _named(events, "fold.window"))
@@ -134,8 +144,27 @@ def test_span_counts_match_the_shapes(rounds):
                                                       "round.program"))
 
 
+def test_fold_stream_counts_its_windows_and_buffers(rounds):
+    """One ``fold.stream`` a round: its windows, and on the staged path
+    (one worker) staging buffers warm from the rounds before, so none is
+    allocated; the fold pool's windows each take fresh memory."""
+    *_, (workers, windows), events = rounds
+    (stream,) = _named(events, "fold.stream")
+    counts = stream[3]
+    assert set(counts) == {"windows", "staging_bytes", "allocs", "overlapped"}
+    assert counts["windows"] == len(windows) == len(_named(events, "fold.window"))
+    assert 0 <= counts["overlapped"] <= len(windows) - 1
+    cols = max(-(-(b - a) // TILE) * TILE for a, b in windows)
+    if workers == 1:
+        assert len(windows) == -(-ELEMS // TILE)
+        assert counts["allocs"] == 0
+        assert counts["staging_bytes"] >= 4 * N * cols
+    else:
+        assert counts["allocs"] == len(windows) and counts["staging_bytes"] == 0
+
+
 def test_traced_round_is_bit_identical(rounds):
-    plain, want, traced, got, _ = rounds
+    plain, want, traced, got, _, _ = rounds
     assert np.array_equal(got.avg_flat, want.avg_flat)
     assert got.kernel_folds == want.kernel_folds == M
     assert (got.wall_clock_s, got.phases_s) == (want.wall_clock_s, want.phases_s)
