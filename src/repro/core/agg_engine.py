@@ -633,8 +633,11 @@ class BatchedBackend(ExecutionBackend):
     def _evaluate_pallas(self, ready: list) -> int:
         """Dispatch the ``ready`` nodes to the Pallas fold — one
         byte-bounded ``fedavg_multi`` per client count, reading inputs in
-        place (no whole-round stack). Returns the number of nodes it
-        folded."""
+        place (no whole-round stack). Nodes go in the order they were
+        recorded, which in a GradsSharding round is plan order (the phase
+        invokes shard j's aggregator j-th), so node j's ``out`` is the
+        j-th view of the call's one result buffer. Returns the number of
+        nodes it folded."""
         import jax
 
         from repro.kernels import ops as kops

@@ -260,8 +260,34 @@ def shard(flat, plan: PartitionPlan) -> list:
     return out
 
 
+def _fold_result(shards: Sequence, plan: PartitionPlan):
+    """The fold's result buffer, where ``shards`` are consecutive f32 views
+    of one, each at its one segment of ``plan``, so that the buffer
+    already is the flat gradient (:func:`repro.kernels.ops.claim_result`
+    hands it out once); else None."""
+    flat = getattr(shards[0], "base", None) \
+        if len(shards) == plan.n_shards else None
+    if not isinstance(flat, np.ndarray) or flat.dtype != np.float32 \
+            or flat.shape != (plan.total,):
+        return None
+    addr = flat.__array_interface__["data"][0]
+    for segs, sh in zip(plan.segments, shards):
+        if len(segs) != 1 or getattr(sh, "base", None) is not flat \
+                or sh.dtype != np.float32 or sh.strides != (4,) \
+                or sh.shape != (segs[0][1] - segs[0][0],) \
+                or sh.__array_interface__["data"][0] != addr + 4 * segs[0][0]:
+            return None
+    from repro.kernels.ops import claim_result
+    return flat if claim_result(flat) else None
+
+
 def reconstruct(shards: Sequence, plan: PartitionPlan):
-    """Concatenate averaged shards back to the full flat gradient."""
+    """Concatenate averaged shards back to the full flat gradient: a new
+    array, or, where the shards are the fold's views laid out as ``plan``
+    (:func:`_fold_result`), the fold's own buffer without a copy."""
+    whole = _fold_result(shards, plan)
+    if whole is not None:
+        return whole
     xp = jnp if isinstance(shards[0], jax.Array) else np
     out = xp.zeros((plan.total,), shards[0].dtype)
     for segs, sh in zip(plan.segments, shards):

@@ -65,7 +65,7 @@ from repro.serverless.faults import FaultModel, StaleBuffer, StalenessPolicy
 from repro.serverless.runtime import FaultPlan, InvocationRecord, \
     LambdaRuntime
 from repro.store import ObjectStore
-from repro.tracing import span
+from repro.tracing import span, tally
 
 MB = 1024 * 1024
 
@@ -254,6 +254,9 @@ def round_prefix(rnd: int) -> str:
 @dataclass
 class AggregationResult:
     topology: str
+    # the round's mean, a new writeable f32 array each round; where the
+    # kernel fold divided straight into it (GradsSharding, one segment per
+    # shard), the store's avg-shard objects are views of it
     avg_flat: np.ndarray
     wall_clock_s: float
     # barrier: per-phase *durations* (wall_clock_s == upload span + their
@@ -1103,12 +1106,17 @@ def run_round(topology: str | Topology,
     # the whole cohort reads the round result back (next round's local
     # training needs it), so read-back op counts stay at cohort size even
     # when the fold covered a subset
-    with span("round.readback", bytes=sum(nb for _k, nb in prog.readback)):
+    with tally("round.readback", bytes=sum(nb for _k, nb in prog.readback),
+               copied=0) as counts:
         values = [store.get(key) for key, _nb in prog.readback]
         if n > 1:
             for key, _nb in prog.readback:
                 store.account_gets(key, n - 1)
         avg = np.asarray(prog.collect(values))
+        # collect copied unless it handed out memory it was given: the
+        # fold's buffer, or a topology's one global value
+        if not any(np.may_share_memory(avg, v) for v in values):
+            counts["copied"] = avg.nbytes
     member_done = _readback_times(sched, runtime, upload, up,
                                   prog.readback, agg_end)
     if order == list(range(n)):

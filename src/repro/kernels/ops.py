@@ -9,6 +9,7 @@ kernels and the numpy mirrors of the aggregation engine and wire codecs.
 from __future__ import annotations
 
 import threading
+import weakref
 from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Callable, Sequence
@@ -84,6 +85,9 @@ _staging_lock = threading.Lock()
 #: windows, and every chip's pipeline but the first
 _lanes: ThreadPoolExecutor | None = None
 _lanes_size = 0
+#: the result buffers of :func:`fedavg_multi` calls that are still alive,
+#: by id, until :func:`claim_result` hands one out
+_results: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -215,8 +219,9 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
     ``workers`` > 1 in interpret mode, where launches are host-bound,
     windows instead run on the fold pool, each in fresh memory.
 
-    Returns a list of (L_j,) f32 means, one per input stack, each newly
-    allocated.
+    Returns a list of (L_j,) f32 means, one per input stack: consecutive
+    views, in stack order, of one newly allocated (sum of L_j,) buffer
+    that the divide writes straight into (:func:`claim_result`).
     """
     if interpret is None:
         interpret = _use_interpret()
@@ -237,7 +242,9 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
         w_host = np.asarray(weights, np.float32)
         w_devs = [jax.device_put(w_host, d) for d in devices]
         div = np.float32(w_host.sum())
-    outs = [np.empty(l, np.float32) for l in lengths]
+    flat = np.empty(offsets[-1], np.float32)
+    _results[id(flat)] = flat
+    outs = [flat[a:b] for a, b in zip(offsets, offsets[1:])]
     from repro.core.fold_pool import PARALLEL_MIN_ELEMS, get_pool, partition
     pool = get_pool(workers)
     fan_out = interpret and pool.workers > 1
@@ -369,6 +376,14 @@ def fedavg_multi(shard_stacks: Sequence, weights=None,
                 if not fan_out:
                     counts["staging_bytes"] = _staging[0].nbytes
     return outs
+
+
+def claim_result(buf) -> bool:
+    """Whether ``buf`` is the buffer one :func:`fedavg_multi` call
+    allocated for its means and no one has claimed yet; it is claimed now.
+    So a result buffer is handed out once, and no array a caller made
+    itself is ever taken for one."""
+    return _results.pop(id(buf), None) is buf
 
 
 def fedavg_shards(client_shards, weights=None, block_rows: int = 32,

@@ -391,6 +391,42 @@ def test_fedavg_multi_fills_the_next_window_while_one_is_in_flight(
     assert counts["overlapped"] == 3
 
 
+def _assert_views_of_one_buffer(means, lengths) -> np.ndarray:
+    """The means are consecutive views, in order, of one f32 buffer of
+    sum(lengths) elements; returns that buffer."""
+    buf = means[0].base
+    assert isinstance(buf, np.ndarray) and buf.dtype == np.float32
+    assert buf.shape == (sum(lengths),) and buf.flags.writeable
+    addr = buf.__array_interface__["data"][0]
+    for off, l, mean in zip(np.cumsum([0] + lengths), lengths, means):
+        assert mean.base is buf and mean.shape == (l,)
+        assert mean.__array_interface__["data"][0] == addr + 4 * off
+    return buf
+
+
+@pytest.mark.parametrize("workers", [1, 2], ids=["staged", "fold-pool"])
+def test_fedavg_multi_divides_into_views_of_one_buffer(monkeypatch, workers):
+    """One call's means are consecutive views of one new buffer, in stack
+    order, across many windows; they are bit-identical to the numpy
+    evaluator, and the buffer shares no memory with the inputs, the
+    staging buffers or an earlier call's buffer."""
+    from repro.kernels import ops
+    n, tile = 4, 32 * 128
+    monkeypatch.setattr(ops, "STAGING_BYTES", 4 * n * tile)
+    rng = np.random.default_rng(15)
+    lengths = [5_000, 3, 9_001, 4_096]
+    stacks = [rng.standard_normal((n, l)).astype(np.float32) for l in lengths]
+    means = ops.fedavg_multi(stacks, workers=workers)
+    again = ops.fedavg_multi(stacks, workers=workers)
+    buf = _assert_views_of_one_buffer(means, lengths)
+    nodes = [LazyAverage(list(stack), None) for stack in stacks]
+    _evaluate_nodes(nodes)
+    for mean, nd, twice in zip(means, nodes, again):
+        assert np.array_equal(mean, nd.out) and np.array_equal(twice, nd.out)
+    for other in [*stacks, *ops._staging.values(), again[0].base]:
+        assert not np.shares_memory(buf, other)
+
+
 def test_fold_windows_refuses_a_tile_over_budget():
     from repro.kernels import ops
     with pytest.raises(ValueError, match="device budget"):

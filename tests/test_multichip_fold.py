@@ -100,6 +100,38 @@ def test_fedavg_multi_on_four_chips_is_bit_identical(m, weighted):
     """)
 
 
+def test_fedavg_multi_on_four_chips_divides_into_one_buffer():
+    """Each chip's windows divide into their own column range of the one
+    buffer: the means are its consecutive views in stack order, equal to
+    the numpy evaluator bit for bit, sharing no memory with the inputs or
+    the eight staging buffers."""
+    out = run_four_devices("""
+        from repro.core.agg_engine import LazyAverage, _evaluate_nodes
+        n, tile = 3, 32 * 128
+        ops.STAGING_BYTES = 4 * n * tile
+        rng = np.random.default_rng(16)
+        lengths = [3 * tile + 5, 7, 4 * tile, 2 * tile + 1]
+        stacks = [rng.standard_normal((n, l)).astype(np.float32)
+                  for l in lengths]
+        means = ops.fedavg_multi(stacks, workers=1,
+                                 devices=jax.local_devices())
+        buf = means[0].base
+        assert buf.dtype == np.float32 and buf.shape == (sum(lengths),)
+        addr = buf.__array_interface__["data"][0]
+        for off, l, mean in zip(np.cumsum([0] + lengths), lengths, means):
+            assert mean.base is buf and mean.shape == (l,)
+            assert mean.__array_interface__["data"][0] == addr + 4 * off
+        nodes = [LazyAverage(list(stack), None) for stack in stacks]
+        _evaluate_nodes(nodes)
+        assert all(np.array_equal(m, nd.out) for m, nd in zip(means, nodes))
+        assert len(ops._staging) == 8
+        for other in [*stacks, *ops._staging.values()]:
+            assert not np.shares_memory(buf, other)
+        print("ONE_BUFFER_OK")
+    """)
+    assert "ONE_BUFFER_OK" in out
+
+
 def test_session_on_four_chips_matches_the_streaming_engine():
     out = run_four_devices("""
         from repro.api import FederatedSession, SessionConfig

@@ -283,3 +283,117 @@ def test_runtime_reset_clears_cumulative_billing():
     assert rt.total_gb_s() > 0
     rt.reset()
     assert rt.total_gb_s() == 0.0
+
+
+# ---------------------------------------------------------------------------
+# One result buffer per round: the kernel fold divides straight into
+# avg_flat, and the read-back hands that buffer out without a copy
+# ---------------------------------------------------------------------------
+
+def _readback_values(session, rnd):
+    return [session.store.peek(k)
+            for k in session.store.list(f"round{rnd:05d}/avg/")]
+
+
+@pytest.mark.parametrize("keep_records", [True, False],
+                         ids=["records", "compacted"])
+def test_kernel_round_hands_out_the_fold_buffer(monkeypatch, keep_records):
+    """On the kernel path with a uniform plan, avg_flat is the buffer the
+    fold divided into: the avg-shard objects are its views. Every round's
+    buffer is new, shares no memory with another round's or with the
+    contributions, stays writeable and keeps its values after later
+    rounds, and is bit-identical to the streaming engine."""
+    monkeypatch.setenv("REPRO_AGG_PALLAS", "1")
+    kernel = FederatedSession(SessionConfig(n_shards=3,
+                                            keep_records=keep_records))
+    ref = FederatedSession(SessionConfig(n_shards=3, engine="streaming"))
+    rounds = [_grads(n=3, size=10_000, seed=s) for s in range(3)]
+    results, kept = [], []
+    for rnd, grads in enumerate(rounds):
+        got = kernel.round(grads)
+        assert got.kernel_folds == 3
+        assert np.array_equal(got.avg_flat, ref.round(grads).avg_flat)
+        shards = _readback_values(kernel, rnd)
+        if keep_records:
+            assert len(shards) == 3
+            assert all(sh.base is got.avg_flat for sh in shards)
+            assert all(np.shares_memory(sh, got.avg_flat) for sh in shards)
+        else:
+            assert shards == []
+        assert got.avg_flat.flags.writeable
+        assert not any(np.shares_memory(got.avg_flat, g) for g in grads)
+        assert not any(np.shares_memory(got.avg_flat, r.avg_flat)
+                       for r in results)
+        results.append(got)
+        kept.append(got.avg_flat.copy())
+    assert all(np.array_equal(r.avg_flat, k) for r, k in zip(results, kept))
+
+
+SIZES = [3_000, 40, 2_500, 4_460]          # 10,000 elements, four tensors
+
+
+@pytest.mark.parametrize("kernel, options, hands_out", [
+    pytest.param(False, {}, False, id="numpy-evaluator"),
+    pytest.param(True, dict(topology="sharded_tree", n_shards=2), False,
+                 id="sharded_tree"),
+    pytest.param(True, dict(partition="balanced", n_shards=2,
+                            tensor_sizes=SIZES), False, id="balanced"),
+    pytest.param(True, dict(partition="layer_contiguous", n_shards=6,
+                            tensor_sizes=SIZES), False,
+                 id="layer_contiguous-empty-shard"),
+    pytest.param(True, dict(partition="layer_contiguous", n_shards=3,
+                            tensor_sizes=SIZES), True,
+                 id="layer_contiguous"),
+    pytest.param(True, dict(topology="lambda_fl"), None, id="lambda_fl"),
+])
+def test_readback_copies_unless_the_shards_are_one_fold_buffer(
+        monkeypatch, kernel, options, hands_out):
+    """The read-back hands out the fold's buffer only where the read-back
+    values are its views at their plan segments (one segment per shard,
+    every shard folded by the one kernel call); everywhere else it copies
+    into a new array, as before. λ-FL's read-back is its one global value,
+    which its collect returns as it is: a root the numpy evaluator wrote,
+    never a fold buffer. Every case is bit-identical to streaming."""
+    monkeypatch.setenv("REPRO_AGG_PALLAS", "1" if kernel else "0")
+    grads = _grads(n=3, size=10_000)
+    session = FederatedSession(SessionConfig(**options))
+    got = session.round(grads)
+    want = FederatedSession(SessionConfig(engine="streaming", **options)) \
+        .round(grads)
+    assert np.array_equal(got.avg_flat, want.avg_flat)
+    assert (got.kernel_folds > 0) == kernel
+    values = _readback_values(session, 0)
+    shared = [np.shares_memory(got.avg_flat, v) for v in values]
+    if hands_out is None:
+        assert shared == [True] and got.avg_flat is values[0]
+        assert got.avg_flat.base is None
+    elif hands_out:
+        assert all(shared) and all(v.base is got.avg_flat for v in values)
+    else:
+        assert not any(shared)
+
+
+def test_kernel_sessions_share_the_process_staging(monkeypatch):
+    """Kernel folds stream through the process's two staging buffers (one
+    worker: the staged path): allocated in a session's first round, then
+    reused by its later rounds and by every other session, and never
+    handed out as part of a round's answer."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_staging", {})
+    monkeypatch.setattr(ops, "STAGING_BYTES", 4 * 3 * 32 * 128)  # one tile
+    monkeypatch.setenv("REPRO_AGG_PALLAS", "1")
+    grads = _grads(n=3, size=10_000)
+    first = FederatedSession(SessionConfig(n_shards=3, workers=1))
+    results = [first.round(grads)]
+    buffers = dict(ops._staging)
+    assert len(buffers) == 2
+    results.append(first.round(grads))
+    second = FederatedSession(SessionConfig(n_shards=3, workers=1))
+    results.append(second.round(grads))
+    assert ops._staging.keys() == buffers.keys()
+    assert all(ops._staging[k] is b for k, b in buffers.items())
+    for r in results:
+        assert r.kernel_folds == 3
+        assert np.array_equal(r.avg_flat, results[0].avg_flat)
+        assert not any(np.shares_memory(r.avg_flat, b)
+                       for b in buffers.values())

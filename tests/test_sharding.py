@@ -87,6 +87,42 @@ def test_shard_reconstruct_roundtrip_balanced(sizes, m):
     np.testing.assert_array_equal(back, flat)
 
 
+@pytest.mark.parametrize("plan", [
+    plan_uniform(4_096, 4),
+    plan_uniform(4_096, 1),
+    plan_layer_contiguous([1_000, 96, 3_000], 3),
+    plan_balanced([1_000, 96, 3_000], 2),
+], ids=["uniform", "one-shard", "layer_contiguous", "balanced"])
+def test_reconstruct_of_caller_shards_is_a_new_array(plan):
+    """Shards of an array the caller made are copied into a new array,
+    whatever their layout."""
+    flat = np.random.default_rng(1).standard_normal(plan.total) \
+        .astype(np.float32)
+    back = reconstruct(shard(flat, plan), plan)
+    assert back is not flat and not np.shares_memory(back, flat)
+    np.testing.assert_array_equal(back, flat)
+
+
+def test_reconstruct_hands_out_a_fold_buffer_once():
+    """The fold's means at their plan segments come back as the fold's own
+    buffer, the first time only: shards of it later, or views of any other
+    buffer, are copied."""
+    from repro.kernels import ops
+    plan = plan_uniform(3_000, 3)
+    stacks = [np.random.default_rng(j).standard_normal((2, 1_000))
+              .astype(np.float32) for j in range(3)]
+    means = ops.fedavg_multi(stacks, workers=1)
+    whole = reconstruct(means, plan)
+    assert all(m.base is whole for m in means)
+    again = reconstruct(means, plan)
+    assert again is not whole and not np.shares_memory(again, whole)
+    np.testing.assert_array_equal(again, whole)
+    swapped = ops.fedavg_multi(stacks[::-1], workers=1)[::-1]
+    back = reconstruct(swapped, plan)
+    assert not any(np.shares_memory(back, m) for m in swapped)
+    np.testing.assert_array_equal(back, whole)
+
+
 # ---------------------------------------------------------------------------
 # Flatten / unflatten
 # ---------------------------------------------------------------------------

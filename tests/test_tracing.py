@@ -133,7 +133,8 @@ def test_span_counts_match_the_shapes(rounds):
     assert _named(events, "round.upload")[0][3] == {"puts": N * M}
     assert _named(events, "round.phases")[0][3] == {"invocations": M}
     assert _named(events, "engine.end_round")[0][3] == {"kernel_folds": M}
-    assert _named(events, "round.readback")[0][3] == {"bytes": ELEMS * 4}
+    assert _named(events, "round.readback")[0][3] == {"bytes": ELEMS * 4,
+                                                      "copied": 0}
     cols = [-(-(b - a) // TILE) * TILE for a, b in windows]
     got = sorted((e[3]["index"], e[3]["n"], e[3]["cols"])
                  for e in _named(events, "fold.window"))
@@ -182,6 +183,24 @@ def test_traced_round_is_bit_identical(rounds):
     assert got.records == want.records
     assert traced.total_cost() == plain.total_cost()
     assert traced.summary() == plain.summary()
+
+
+@pytest.mark.parametrize("kernel, copied", [(True, 0), (False, ELEMS * 4)],
+                         ids=["kernel", "numpy"])
+def test_readback_counts_the_bytes_collect_copied(monkeypatch, tmp_path,
+                                                  kernel, copied):
+    """``round.readback`` carries ``copied``: none where the fold divided
+    into the round's result buffer, the whole gradient where the numpy
+    evaluator's separate shards are copied into a new one."""
+    monkeypatch.setenv("REPRO_AGG_PALLAS", "1" if kernel else "0")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        got = _session("identity", 1).round(_grads(0))
+    assert got.kernel_folds == (M if kernel else 0)
+    (readback,) = _named(_program_events(str(tmp_path)), "round.readback")
+    assert readback[3] == {"bytes": ELEMS * 4, "copied": copied}
 
 
 def test_four_chip_fold_spans_count_each_chip():
